@@ -64,4 +64,7 @@ def run(n_points: int = 200_000, dim: int = 8, n_sites: int = 8, k_local: int = 
 
 
 if __name__ == "__main__":
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
     run()

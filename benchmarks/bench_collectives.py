@@ -70,6 +70,9 @@ def main() -> int:
     ap.add_argument("--sites", type=int, default=8)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
     run(n_sites=args.sites, out=args.out)
     return 0
 

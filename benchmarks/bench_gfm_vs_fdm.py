@@ -67,4 +67,7 @@ def run(n_tx: int = 40_000, n_items: int = 96, n_sites: int = 8, k: int = 4, min
 
 
 if __name__ == "__main__":
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -176,9 +176,10 @@ def main() -> int:
         "--tuned-out", default=None, help="persist the tuned table JSON here"
     )
     args = ap.parse_args()
-    from repro.launch.mesh import tuned_platform
+    from repro.launch.mesh import enable_compile_cache, tuned_platform
 
     tuned_platform()  # apply the tuned XLA flag set (GPU) before first use
+    enable_compile_cache()
     if args.smoke:
         from repro.kernels import autotune as at
 

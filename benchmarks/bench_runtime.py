@@ -186,9 +186,10 @@ def main() -> None:
         "process executes only its owned sites and ships results)",
     )
     args = ap.parse_args()
-    from repro.launch.mesh import tuned_platform
+    from repro.launch.mesh import enable_compile_cache, tuned_platform
 
     tuned_platform()  # apply the tuned XLA flag set (GPU) before first use
+    enable_compile_cache()
     run(
         smoke=args.smoke,
         out=args.out,

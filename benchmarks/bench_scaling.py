@@ -48,4 +48,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
     run()

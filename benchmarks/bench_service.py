@@ -204,6 +204,9 @@ def main() -> int:
                          "fused wall time against it")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
     kw = dict(
         backend=args.backend, requests=args.requests, tenants=args.tenants,
         burst=args.burst, n_sites=args.n_sites, n_items=args.n_items,

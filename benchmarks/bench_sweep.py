@@ -379,6 +379,9 @@ def main() -> None:
         help="Pallas kernels: auto = TPU only (interpret mode is too slow to sweep on CPU)",
     )
     args = ap.parse_args()
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
     run(
         smoke=args.smoke,
         out=args.out,

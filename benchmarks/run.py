@@ -17,6 +17,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="", help="substring filter")
     args = ap.parse_args()
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
 
     from benchmarks import (
         bench_clustering,

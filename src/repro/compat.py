@@ -1,11 +1,10 @@
-"""Version compatibility for the jax API surface this repo rides on.
+"""Host-side payload packing for the cross-process wire, and a flat
+view of a compiled program's cost analysis.
 
-The repo targets current jax (``jax.shard_map``, ``AbstractMesh(axis_sizes,
-axis_names)``, dict-returning ``Compiled.cost_analysis``) but must also run
-on the 0.4.x line baked into the CI/dev containers, where those entry
-points live elsewhere or return different shapes.  Everything
-version-sensitive is funnelled through here so the rest of the codebase
-stays on the modern spelling.
+The repo is pinned to one jax release (see ``pyproject.toml``) and calls
+``jax.shard_map``, ``jax.sharding.get_abstract_mesh`` and
+``AbstractMesh(axis_sizes, axis_names)`` directly; nothing here papers
+over another jax version.
 """
 
 from __future__ import annotations
@@ -15,44 +14,6 @@ from typing import Any
 
 import jax
 import numpy as np
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` (new) / ``jax.experimental.shard_map`` (0.4.x).
-
-    The replication-checking kwarg was renamed check_rep -> check_vma; we
-    accept the new name and translate.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
-def get_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh`` where it exists, else None.
-
-    Callers treat None as "no abstract-mesh tracking" and fall back to the
-    concrete context mesh (the pre-abstract-mesh behaviour).
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    return fn() if fn is not None else None
-
-
-def abstract_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """``AbstractMesh(axis_sizes, axis_names)``; 0.4.x wants one tuple of
-    (name, size) pairs."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
 
 
 def _leaf_to_host(leaf: Any) -> Any:
@@ -90,12 +51,5 @@ def unpack_payload(data: bytes) -> Any:
 
 
 def cost_analysis_dict(compiled) -> dict[str, Any]:
-    """``Compiled.cost_analysis()`` as a flat dict.
-
-    Old jaxlib returns a one-element list of dicts (one per computation);
-    new jax returns the dict directly; either may be empty/None.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+    """``Compiled.cost_analysis()`` as a dict (``{}`` when XLA gives none)."""
+    return dict(compiled.cost_analysis() or {})

@@ -102,13 +102,15 @@ def pairwise_sq_dists(a: jax.Array, b: jax.Array) -> jax.Array:
     """(Na, D), (Nb, D) -> (Na, Nb) squared euclidean distances.
 
     MXU-friendly form |a|^2 + |b|^2 - 2 a.b^T (same identity the Pallas
-    kernel uses); clamped at 0 against rounding.
+    kernel uses); clamped at 0 against rounding.  The matmul runs at
+    ``Precision.HIGHEST``: at TPU's default (one bf16 pass) nearest-centre
+    ranks would differ from the kernel's f32 contraction.
     """
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
     a2 = jnp.sum(a**2, axis=-1)[:, None]
     b2 = jnp.sum(b**2, axis=-1)[None, :]
-    d2 = a2 + b2 - 2.0 * (a @ b.T)
+    d2 = a2 + b2 - 2.0 * jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(d2, 0.0)
 
 

@@ -33,12 +33,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.kmeans import kmeans
 from repro.core.stats import (
     SuffStats,
     merge_cost,
-    pairwise_sq_dists,
     stack_site_stats,
 )
 
@@ -167,18 +165,22 @@ def perturb_site(
     glabel = merged.labels[point_slot]  # (n,) global slot per point
 
     st = merged.stats
-    d2_all = pairwise_sq_dists(x, st.centers)  # (n, M)
-
     alive = st.sizes > 0
 
     # --- border candidates: top-b farthest per global cluster, this site ---
-    own_d2 = jnp.take_along_axis(d2_all, glabel[:, None], axis=1)[:, 0]  # (n,)
-    # score matrix (M, n): distance if point belongs to cluster else -inf
-    belong = glabel[None, :] == jnp.arange(m, dtype=jnp.int32)[:, None]  # (M, n)
-    scores = jnp.where(belong, own_d2[None, :], -jnp.inf)
-    # top-b point indices per cluster slot
-    _, cand_idx = jax.lax.top_k(scores, min(b, n))  # (M, b)
-    cand_valid = jnp.take_along_axis(scores, cand_idx, axis=1) > -jnp.inf
+    # Each point is scored against its OWN global centre only, and the
+    # per-cluster top-b comes from one sort by (cluster, -distance,
+    # index), so nothing (n, M)-sized is built: at 200 sites x 20
+    # sub-clusters that matrix alone is n x 4000 floats per site.
+    own_d2 = jnp.sum((x - st.centers[glabel]) ** 2, axis=-1)  # (n,)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    order = jnp.lexsort((pos, -own_d2, glabel))  # cluster-major, farthest first
+    slot_ids = jnp.arange(m, dtype=jnp.int32)
+    start = jnp.searchsorted(glabel[order], slot_ids, side="left")  # (M,)
+    count = jnp.searchsorted(glabel[order], slot_ids, side="right") - start
+    rank = jnp.arange(min(b, n), dtype=jnp.int32)[None, :]  # (1, b)
+    cand_valid = rank < count[:, None]  # (M, b)
+    cand_idx = order[jnp.where(cand_valid, start[:, None] + rank, 0)]
 
     cand_flat = cand_idx.reshape(-1)  # (M*b,)
     valid_flat = cand_valid.reshape(-1)
@@ -308,7 +310,7 @@ def vcluster_shard_map(mesh, axis: str, cfg: VClusterConfig = VClusterConfig()):
         labels, _ = perturb_site(x, slots, merged, cfg.border_candidates)
         return labels, merged
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),

@@ -7,7 +7,14 @@ formulation: d²(x,c) = ‖x‖² + ‖c‖² − 2·x·cᵀ so the dominant ter
 Tiling: grid over N tiles.  Each program holds one (TN, D) block of points
 and the full (K, D) center set in VMEM (K and D are padded to the 128-lane
 boundary by ``ops.kmeans_assign``).  VMEM footprint per program:
-TN·D + K·D + TN·K floats — e.g. TN=256, D=128, K=128: ~49 KB·f32 ≪ 16 MB.
+TN·D + K·D + TN·K floats — e.g. TN=1024, D=128, K=128: ~1.1 MB ≪ 16 MB.
+The (N,) results are written as lane-dense (1, N) rows in (1, TN) blocks:
+a 1-D block narrower than its array, or the (1, TN) block that
+``jax.vmap`` makes of one, is refused by Mosaic's (8, 128) tiling rule,
+while a (1, TN) block of a (1, N) or vmapped (S, 1, N) array is not.
+The matmul runs at ``Precision.HIGHEST`` (f32 contraction on the MXU), the
+precision ``core.stats.pairwise_sq_dists`` and ``ref.kmeans_assign_ref``
+use, so the kernel and the jnp path rank centres alike on the chip.
 
 Padding contract (enforced by the wrapper): padded D columns are zero in
 both x and centers (distances unchanged); padded K rows carry +BIG
@@ -34,11 +41,14 @@ def _kernel(x_ref, c_ref, assign_ref, mind2_ref):
     c2 = jnp.sum(c * c, axis=-1)[None, :]  # (1, K)
     # MXU: (TN, D) @ (D, K)
     xc = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # (TN, K)
     d2 = x2 + c2 - 2.0 * xc
-    assign_ref[...] = jnp.argmin(d2, axis=-1).astype(jnp.int32)
-    mind2_ref[...] = jnp.maximum(jnp.min(d2, axis=-1), 0.0)
+    # (TN,) per-point results stored as one lane-dense (1, TN) row
+    assign_ref[...] = jnp.argmin(d2, axis=-1).astype(jnp.int32)[None, :]
+    mind2_ref[...] = jnp.maximum(jnp.min(d2, axis=-1), 0.0)[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -73,13 +83,13 @@ def kmeans_assign_pallas(
             pl.BlockSpec((k, d), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((np_,), jnp.int32),
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
+            jax.ShapeDtypeStruct((1, np_), jnp.int32),
+            jax.ShapeDtypeStruct((1, np_), jnp.float32),
         ],
         interpret=interpret,
     )(x_p, centers)
-    return assign[:n], mind2[:n]
+    return assign[0, :n], mind2[0, :n]

@@ -1,8 +1,11 @@
 """Jitted public wrappers around the Pallas kernels.
 
-Handle padding/layout so callers pass natural shapes; select interpret
-mode automatically off-TPU (this container is CPU-only — Mosaic kernels
-are VALIDATED via the interpreter and TARGET TPU).
+Handle padding/layout so callers pass natural shapes.  On a TPU backend
+every kernel is compiled by Mosaic (``chip_smoke.py`` asserts that each
+compiled program holds a ``tpu_custom_call``, and
+``tests/test_tpu_compile.py`` compiles them for a described v5e); on any
+other backend the same kernels run in Pallas interpret mode, which is
+how the CPU test suite checks them against ``ref``.
 
 Block-size seam: every mining-kernel wrapper takes ``block=`` —
 

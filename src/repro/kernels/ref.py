@@ -17,7 +17,7 @@ def kmeans_assign_ref(x: jax.Array, centers: jax.Array) -> tuple[jax.Array, jax.
     d2 = (
         jnp.sum(x**2, axis=-1)[:, None]
         + jnp.sum(c**2, axis=-1)[None, :]
-        - 2.0 * x @ c.T
+        - 2.0 * jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
     )
     d2 = jnp.maximum(d2, 0.0)
     return jnp.argmin(d2, axis=-1).astype(jnp.int32), jnp.min(d2, axis=-1)

@@ -8,12 +8,19 @@ Layout is transposed for TPU lane tiling: transactions arrive as (W, N)
 int32 and candidates as (W, C) int32 so the *vector* dimensions (N, C) sit
 on the 128-wide lane axis and W (≤ 32 words = 1024 items) is a small
 static leading loop.  Each program materialises a (TN, TC) hit block on
-the VPU and reduces it into a (TC,) partial; the grid is (C tiles, N
+the VPU and reduces it into a (1, TC) partial; the grid is (C tiles, N
 tiles) with N innermost so the output block accumulates sequentially
 (TPU grid order guarantees the revisiting program sees its prior value).
 
-VMEM per program: W·(TN + TC)·4 B + TN·TC·4 B ≈ 32·(512+512)·4 + 512²·4
-≈ 1.2 MB ≪ 16 MB.
+Outputs are lane-dense (1, C) rows written in (1, TC) blocks.  Mosaic
+refuses a 1-D block narrower than its array, and ``jax.vmap`` (the
+``ops.*_sites`` forms) turns a 1-D block into a (1, TC) block of an
+(S, C) array, which breaks the (8, 128) tiling rule; a (1, TC) block of
+a (1, C) row, or of its vmapped (S, 1, C) form, does not.  The prune
+kernel's two scalars ride as one (1, 2) row for the same reason.
+
+VMEM per program: W·(TN + TC)·4 B + TN·TC·4 B + 2·TC·4 B for the output
+rows — at the largest lattice point, W=32, TN=TC=1024: ≈ 4.5 MB ≪ 16 MB.
 """
 
 from __future__ import annotations
@@ -36,7 +43,7 @@ def _kernel(tx_ref, mask_ref, out_ref):
         t = tx[ww][:, None]  # (TN, 1)
         m = mk[ww][None, :]  # (1, TC)
         hit &= (t & m) == m
-    partial = jnp.sum(hit.astype(jnp.int32), axis=0)  # (TC,)
+    partial = jnp.sum(hit.astype(jnp.int32), axis=0, keepdims=True)  # (1, TC)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -84,10 +91,10 @@ def support_count_pallas(
             pl.BlockSpec((w, block_n), lambda i, j: (0, j)),
             pl.BlockSpec((w, block_c), lambda i, j: (0, i)),
         ],
-        out_specs=pl.BlockSpec((block_c,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((cp_,), jnp.int32),
+        out_specs=pl.BlockSpec((1, block_c), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, cp_), jnp.int32),
         interpret=interpret,
-    )(tx_p, mk_p)[:c]
+    )(tx_p, mk_p)[0, :c]
     if np_ != n:
         empty_mask = jnp.all(masks_t == 0, axis=0)  # matches the zero pad rows
         out = out - jnp.where(empty_mask, jnp.int32(np_ - n), jnp.int32(0))
@@ -99,7 +106,9 @@ def _prune_kernel(tx_ref, mask_ref, par_ref, out_ref, freq_ref):
     transaction tile each candidate block corrects its own pad-row
     overcount (all-zero masks match the zero pad rows; ``par_ref[0]``
     carries the pad-row count) and emits the ``count >= min_count``
-    frequent flag (``par_ref[1]``) next to the final count — one device
+    frequent flag (``par_ref[1]``) next to the final count; ``par_ref``
+    is one (1, 2) row so its block is the whole array, with or without
+    the vmapped site axis — one device
     pass returns both, so the level loop thresholds without a host
     round-trip of the raw count vector."""
     w = tx_ref.shape[0]
@@ -110,7 +119,7 @@ def _prune_kernel(tx_ref, mask_ref, par_ref, out_ref, freq_ref):
         t = tx[ww][:, None]
         m = mk[ww][None, :]
         hit &= (t & m) == m
-    partial = jnp.sum(hit.astype(jnp.int32), axis=0)  # (TC,)
+    partial = jnp.sum(hit.astype(jnp.int32), axis=0, keepdims=True)  # (1, TC)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -122,12 +131,12 @@ def _prune_kernel(tx_ref, mask_ref, par_ref, out_ref, freq_ref):
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _finalize():
-        empty = jnp.ones((mk.shape[1],), dtype=jnp.bool_)
+        empty = jnp.ones((1, mk.shape[1]), dtype=jnp.bool_)
         for ww in range(w):
-            empty &= mk[ww] == 0
-        corrected = out_ref[...] - jnp.where(empty, par_ref[0], jnp.int32(0))
+            empty &= mk[ww : ww + 1] == 0
+        corrected = out_ref[...] - jnp.where(empty, par_ref[0, 0], jnp.int32(0))
         out_ref[...] = corrected
-        freq_ref[...] = (corrected >= par_ref[1]).astype(jnp.int32)
+        freq_ref[...] = (corrected >= par_ref[0, 1]).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_c", "interpret"))
@@ -158,7 +167,7 @@ def support_count_prune_pallas(
     cp_ = pad_to(max(c, block_c), block_c)
     tx_p = tx_t if np_ == n else jnp.zeros((w, np_), tx_t.dtype).at[:, :n].set(tx_t)
     mk_p = masks_t if cp_ == c else jnp.zeros((w, cp_), masks_t.dtype).at[:, :c].set(masks_t)
-    params = jnp.stack([jnp.full((), np_ - n, jnp.int32), mc])  # (2,)
+    params = jnp.stack([jnp.full((), np_ - n, jnp.int32), mc])[None, :]  # (1, 2)
     grid = (cp_ // block_c, np_ // block_n)  # N innermost → sequential accumulation
     counts, freq = pl.pallas_call(
         _prune_kernel,
@@ -166,16 +175,16 @@ def support_count_prune_pallas(
         in_specs=[
             pl.BlockSpec((w, block_n), lambda i, j: (0, j)),
             pl.BlockSpec((w, block_c), lambda i, j: (0, i)),
-            pl.BlockSpec((2,), lambda i, j: (0,)),
+            pl.BlockSpec((1, 2), lambda i, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_c,), lambda i, j: (i,)),
-            pl.BlockSpec((block_c,), lambda i, j: (i,)),
+            pl.BlockSpec((1, block_c), lambda i, j: (0, i)),
+            pl.BlockSpec((1, block_c), lambda i, j: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((cp_,), jnp.int32),
-            jax.ShapeDtypeStruct((cp_,), jnp.int32),
+            jax.ShapeDtypeStruct((1, cp_), jnp.int32),
+            jax.ShapeDtypeStruct((1, cp_), jnp.int32),
         ],
         interpret=interpret,
     )(tx_p, mk_p, params)
-    return counts[:c], freq[:c].astype(jnp.bool_)
+    return counts[0, :c], freq[0, :c].astype(jnp.bool_)

@@ -76,6 +76,28 @@ def tuned_platform(platform: str | None = None) -> str:
     return platform or _probable_backend()
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory — call it first thing in an entry point, before
+    anything compiles (``chip_smoke.py``, ``python -m repro.launch.serve``
+    and the benchmark mains do; tests do not).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    no other directory is set here.  Otherwise the cache lives in
+    ``.jax_cache/`` at the root of the checkout: a fixed path, because
+    the path is part of each entry's key and a moving directory never
+    hits.  The minimum compile time worth caching is lowered so the
+    one-to-two-second Pallas kernel compiles are kept too."""
+    import os
+    from pathlib import Path
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = Path(__file__).resolve().parents[3]
+        jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    return jax.config.jax_compilation_cache_dir
+
+
 def _probable_backend() -> str:
     """The backend jax will (or did) pick, WITHOUT forcing backend init
     when the answer is already knowable from the environment — XLA_FLAGS
@@ -150,10 +172,7 @@ def init_multihost(
     # so the collective flag is set first and the backend only queried
     # after init.
     if cpu_collectives:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)
-        except Exception:
-            pass  # older jaxlib: collectives stay single-process
+        jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)
     try:
         jax.distributed.initialize(
             coordinator_address=coordinator_address,

@@ -37,8 +37,9 @@ arXiv:1412.2673) is a bursty stream of arrivals from many users.
     share, cache hit, backend used) in the same spirit as the engine's
     ``RunReport``, JSON-serializable for the CI smoke's artifact.
 
-CLI driver (bursty synthetic multi-tenant trace; ``--check`` gates the
-fairness bound, cache hits and coalescing for CI)::
+CLI driver (bursty synthetic multi-tenant trace; exits non-zero when any
+request failed; ``--check`` also gates the fairness bound, cache hits,
+coalescing and cross-request fusion for CI)::
 
     PYTHONPATH=src python -m repro.launch.serve --requests 50 --tenants 3 \
         --backend batched --check --ledger-out service_ledger.json
@@ -50,7 +51,7 @@ import argparse
 import json
 import sys
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -58,6 +59,7 @@ import numpy as np
 
 from repro.core.apriori import DeltaApriori
 from repro.data.synthetic import gaussian_mixture, ibm_transactions
+from repro.launch.mesh import enable_compile_cache
 from repro.runtime.cache import ResultCache, params_key
 from repro.runtime.gridruntime import GridRuntime
 from repro.workflow.registry import app_names, get_workload, workloads
@@ -152,6 +154,11 @@ class MiningService:
         self.exec_groups = 0
         self.fused_requests = 0
         self.device_dispatches = 0
+        # fused dispatches that threw and were re-run serially: the
+        # service keeps serving, but the fallback is never silent (the
+        # newest exception texts are kept, bounded like the failure memo)
+        self.fused_fallbacks = 0
+        self.fused_fallback_errors: deque[str] = deque(maxlen=int(failure_memo_capacity))
         # failed-execution ledger: real failed attempts, plus the
         # short-circuits served from the failure memo — a bounded map
         # keyed by the full execution key (dataset VERSION included, so
@@ -421,13 +428,15 @@ class MiningService:
         """Execute one bucket of same-signature execution groups: >= 2
         groups attempt ONE fused dispatch (falling back to serial
         per-group execution if the fused attempt throws — fusion is an
-        optimization, never a correctness dependency); solo groups run
-        the serial path directly."""
+        optimization, never a correctness dependency, but each fallback
+        is counted in the ledger with its exception text); solo groups
+        run the serial path directly."""
         if len(bucket) >= 2:
             try:
                 return self._execute_fused(bucket)
-            except Exception:  # noqa: BLE001 — fall back to per-group serial
-                pass
+            except Exception as e:  # noqa: BLE001 — fall back to per-group serial
+                self.fused_fallbacks += 1
+                self.fused_fallback_errors.append(f"{type(e).__name__}: {e}")
         finished: list[int] = []
         for ekey, ckey, reqs in bucket:
             rep = reqs[0]
@@ -568,6 +577,8 @@ class MiningService:
             "exec_groups": self.exec_groups,
             "fused_requests": self.fused_requests,
             "device_dispatches": self.device_dispatches,
+            "fused_fallbacks": self.fused_fallbacks,
+            "fused_fallback_errors": list(self.fused_fallback_errors),
             "failures": self.failures,
             "failure_memo_hits": self.failure_memo_hits,
             "rejected": self.queues.rejected + self.invalid,
@@ -730,6 +741,7 @@ def main(argv=None) -> int:
                     help="assert fairness bound, cache hits, coalescing and "
                          "cross-request fusion (CI gate)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     rng = np.random.default_rng(args.seed)
     svc = _build_service(args)
@@ -772,6 +784,7 @@ def main(argv=None) -> int:
     print(f"[serve] exec_groups={led['exec_groups']} "
           f"device_dispatches={led['device_dispatches']} "
           f"fused_requests={led['fused_requests']} "
+          f"fused_fallbacks={led['fused_fallbacks']} "
           f"failures={led['failures']} memo_hits={led['failure_memo_hits']}")
     print(f"[serve] throughput={len(done) / max(wall, 1e-9):.1f} req/s "
           f"latency p50={np.percentile(lat, 50) * 1e3:.1f}ms "
@@ -788,10 +801,12 @@ def main(argv=None) -> int:
             json.dump(led, f, indent=2, default=float)
         print(f"[serve] ledger -> {args.ledger_out}")
 
+    if failed:
+        print(f"[serve] FAILED: {len(failed)} requests failed: {failed[0]['error']}",
+              file=sys.stderr)
+        return 1
     if args.check:
         problems: list[str] = []
-        if failed:
-            problems.append(f"{len(failed)} requests failed: {failed[0]['error']}")
         if led["cache"]["hits"] < 1:
             problems.append("expected cache hits on repeated queries, got 0")
         if led["coalesced"] < 1:

@@ -27,7 +27,6 @@ from typing import Any
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.stats import SuffStats
 from repro.core.vclustering import (
     MergeResult,
@@ -243,7 +242,7 @@ class GridRuntime:
                 gathered = jax.lax.all_gather(st, axis)  # (s, k, ...) tiny
                 return merge_gathered(gathered, cfg)
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 body, mesh=mesh, in_specs=(P(axis),), out_specs=P(), check_vma=False
             )
             return fn(sharded)

@@ -210,11 +210,9 @@ def constrain(x: jax.Array, logical_axes: Sequence[str | None]) -> jax.Array:
     if not _ACTIVE:
         return x
     mesh, rules = _ACTIVE[-1]
-    from repro.compat import get_abstract_mesh
-
     pspec = logical_to_pspec(logical_axes, x.shape, rules, mesh)
-    am = get_abstract_mesh()
-    if am is not None and am.shape:
+    am = jax.sharding.get_abstract_mesh()
+    if am.shape:
         manual = {
             name
             for name, ty in zip(am.axis_names, am.axis_types)

@@ -322,3 +322,42 @@ def test_ledger_records_shape():
                   "compute_s", "service_s", "error"):
         assert field in rec
     assert rec["status"] == "done" and rec["error"] is None
+
+
+def test_fused_fallback_is_ledgered():
+    """A fused dispatch that throws falls back to serial per-group runs;
+    the requests still complete, and the fallback is counted with its
+    exception text instead of passing in silence."""
+    svc = _service()
+
+    def boom(*_a, **_kw):
+        raise RuntimeError("fused dispatch refused")
+
+    svc.runtime.run_many = boom
+    rids = [svc.submit("a", "fdm", "tx", {"k": 2, "minsup": 0.3}),
+            svc.submit("b", "fdm", "tx", {"k": 2, "minsup": 0.45})]
+    svc.step(max_requests=4)
+    assert all(svc.poll(r) == "done" for r in rids)
+    led = svc.ledger()
+    assert led["fused_fallbacks"] == 1 and led["fused_requests"] == 0
+    assert led["fused_fallback_errors"] == ["RuntimeError: fused dispatch refused"]
+    assert led["failures"] == 0
+    json.dumps(led)
+
+
+def test_cli_exits_nonzero_on_failed_requests(monkeypatch, capsys):
+    """``python -m repro.launch.serve`` fails when any request failed,
+    with or without ``--check``."""
+    from repro.launch import serve
+
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    args = ["--requests", "6", "--tenants", "2", "--append-every", "0"]
+    assert serve.main(args) == 0
+
+    def boom(self, rep):
+        raise RuntimeError("execution refused")
+
+    monkeypatch.setattr(serve.MiningService, "_execute", boom)
+    monkeypatch.setattr(serve.MiningService, "_execute_fused", boom)
+    assert serve.main(args) == 1
+    assert "requests failed: RuntimeError: execution refused" in capsys.readouterr().err

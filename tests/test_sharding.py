@@ -2,13 +2,13 @@
 prevention, spec building — pure-host tests (AbstractMesh, no devices)."""
 
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.sharding import BASELINE, GRIDLOCAL, ShapeAxes, logical_to_pspec
 
-MESH1 = abstract_mesh((16, 16), ("data", "model"))
-MESH2 = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestLogicalToPspec:
