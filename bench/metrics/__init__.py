@@ -1,0 +1,2 @@
+"""Per-layer metric readers, one file per metric, found by the metric's
+name; ``work`` holds the kernels' operation and byte counts."""

@@ -1,0 +1,86 @@
+"""What a run is asked to do, found by name: the cell in ``BENCHMARK.json``,
+its configuration under ``bench/configs/``, its traffic mix under
+``bench/traffic/``, its per-layer metric readers under ``bench/metrics/``,
+and the chip's peaks in ``bench/peaks.json``.
+
+Nothing here knows a cell, a configuration, a mix or a metric by name:
+a later change adds one with new files and ``BENCHMARK.json`` entries.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+from dataclasses import dataclass
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parent
+ROOT = BENCH.parent
+
+
+class SpecError(RuntimeError):
+    """The benchmark's files do not describe the requested run."""
+
+
+@dataclass(frozen=True)
+class Cell:
+    name: str
+    chips: int
+    config: dict  # the configuration file, as run
+    traffic: dict  # the traffic mix's parameters
+    end_to_end: tuple[dict, ...]  # the metric entries this cell reports
+    per_layer: tuple[dict, ...]
+
+
+def _load_json(path: Path) -> dict:
+    try:
+        return json.loads(path.read_text())
+    except FileNotFoundError:
+        raise SpecError(f"missing file {path}") from None
+
+
+def _reports(metric: dict, cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def load_cell(name: str, root: Path = ROOT) -> Cell:
+    """The cell ``name`` with its configuration and traffic mix."""
+    spec = _load_json(root / "BENCHMARK.json")
+    cells = {w["name"]: w for w in spec["workloads"]}
+    if name not in cells:
+        raise SpecError(f"no workload {name!r} in BENCHMARK.json (have {sorted(cells)})")
+    w = cells[name]
+    configs = {c["name"]: c for c in spec["configs"]}
+    if w["config"] not in configs:
+        raise SpecError(f"workload {name!r} names unknown config {w['config']!r}")
+    config = _load_json(root / configs[w["config"]]["file"])
+    traffic = _load_json(root / "bench" / "traffic" / f"{w['traffic']}.json")
+    return Cell(
+        name=name,
+        chips=int(w["chips"]),
+        config=config,
+        traffic=traffic,
+        end_to_end=tuple(m for m in spec["end_to_end"] if _reports(m, name)),
+        per_layer=tuple(m for m in spec["per_layer"] if _reports(m, name)),
+    )
+
+
+def metric_reader(name: str, root: Path = ROOT):
+    """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
+    path = root / "bench" / "metrics" / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"per-layer metric {name!r} has no reader at {path}")
+    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod.read
+
+
+def peaks(device_kind: str, root: Path = ROOT) -> dict:
+    """The published peaks of one chip of ``device_kind``; a kind that is
+    not in the table is an error, never a default."""
+    table = _load_json(root / "bench" / "peaks.json")["devices"]
+    if device_kind not in table:
+        raise SpecError(f"no peaks for device kind {device_kind!r} in bench/peaks.json "
+                        f"(have {sorted(table)})")
+    return table[device_kind]
