@@ -78,29 +78,16 @@ def check(ok: bool, what: str) -> None:
         raise SmokeError(what)
 
 
-class CompileClock:
-    """Sums JAX's own compile-duration events (tracing, lowering, backend
-    compile) so each phase can report its compile time apart from its
-    wall time."""
-
-    def __init__(self):
-        import jax
-
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_kw) -> None:
-        if event.startswith("/jax/core/compile/"):
-            self.total += duration
-
-
 @contextmanager
-def phase(name: str, clock: CompileClock | None, times: dict):
-    c0 = clock.total if clock is not None else 0.0
+def phase(name: str, clock, times: dict):
+    """Time one phase; ``clock`` is ``repro.obs.compiles`` (or None), so
+    the phase reports the seconds of its XLA backend compiles apart from
+    its wall time."""
+    c0 = clock() if clock is not None else None
     t0 = time.perf_counter()
     yield
     wall = time.perf_counter() - t0
-    comp = (clock.total - c0) if clock is not None else float("nan")
+    comp = (clock() - c0).seconds if clock is not None else float("nan")
     times[name] = {"wall_s": wall, "compile_s": comp}
     log(f"phase {name}: wall {wall:.3f} s, of which compile {comp:.3f} s")
 
@@ -366,7 +353,7 @@ def service_phase(
     k_local: int,
     n_components: int,
     seed: int,
-    clock: CompileClock | None = None,
+    clock=None,
 ) -> dict:
     """The two-tenant service run and every check on it.  Returns the
     ledger summary and per-step wall/compile seconds."""
@@ -576,7 +563,7 @@ def main(argv=None) -> int:
         print(f"[smoke] FAILED: {e}", file=sys.stderr)
         return 2
     log(f"compile cache: {cache}")
-    clock = CompileClock()
+    from repro.obs import compiles as clock
     times: dict = {}
     try:
         if args.chips == 4:

@@ -9,6 +9,14 @@ pure-jnp oracle here or by the Pallas TPU kernel in
 Candidate *generation* (level-wise join + prune) is classic set algebra
 with data-dependent sizes; it stays on host exactly as in the paper, where
 the protocol is orchestrated at the grid-job level anyway.
+
+Each level's phases open profiler spans (``repro.obs.span``):
+``repro.level.join`` (candidate generation), ``repro.level.stage`` (a
+count's inputs: the candidates packed into masks and, in the fused
+site-axis forms, the padded site tables, all uploaded),
+``repro.level.count`` (the device dispatch and the wait for its
+counts), ``repro.level.count1`` (the singleton count on the host) and
+``repro.level.fold`` (counts into per-site dicts and frequent lists).
 """
 
 from __future__ import annotations
@@ -21,6 +29,8 @@ from itertools import combinations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs import span
 
 Itemset = tuple[int, ...]  # always sorted
 
@@ -94,16 +104,19 @@ def count_supports(
     """Support counts for ``itemsets`` on one site's DB.  Returns (C,) int64."""
     if not itemsets:
         return np.zeros((0,), dtype=np.int64)
-    masks_np = pack_itemsets(itemsets, db.n_items)
+    with span("repro.level.stage"):
+        masks_np = pack_itemsets(itemsets, db.n_items)
     if backend == "kernel":
         from repro.kernels import ops
 
-        out = ops.support_count(db.packed, jnp.asarray(masks_np))
-        return np.asarray(out, dtype=np.int64)
-    outs = []
-    for s in range(0, masks_np.shape[0], block_c):
-        outs.append(np.asarray(_count_block(db.packed, jnp.asarray(masks_np[s : s + block_c]))))
-    return np.concatenate(outs).astype(np.int64)
+        with span("repro.level.count"):
+            out = ops.support_count(db.packed, jnp.asarray(masks_np))
+            return np.asarray(out, dtype=np.int64)
+    with span("repro.level.count"):
+        outs = []
+        for s in range(0, masks_np.shape[0], block_c):
+            outs.append(np.asarray(_count_block(db.packed, jnp.asarray(masks_np[s : s + block_c]))))
+        return np.concatenate(outs).astype(np.int64)
 
 
 def count_supports_prune(
@@ -125,9 +138,11 @@ def count_supports_prune(
     if backend == "kernel":
         from repro.kernels import ops
 
-        masks_np = pack_itemsets(itemsets, db.n_items)
-        cnt, freq = ops.support_count_prune(db.packed, jnp.asarray(masks_np), int(min_count))
-        return np.asarray(cnt, dtype=np.int64), np.asarray(freq)
+        with span("repro.level.stage"):
+            masks_np = pack_itemsets(itemsets, db.n_items)
+        with span("repro.level.count"):
+            cnt, freq = ops.support_count_prune(db.packed, jnp.asarray(masks_np), int(min_count))
+            return np.asarray(cnt, dtype=np.int64), np.asarray(freq)
     sup = count_supports(db, itemsets, backend=backend, block_c=block_c)
     return sup, sup >= int(min_count)
 
@@ -144,6 +159,21 @@ def _count_block_sites(dbs: jax.Array, masks: jax.Array) -> jax.Array:
     site-axis form of ``_count_block``: one device dispatch for the
     whole fan-out."""
     return jax.vmap(_count_block)(dbs, masks)
+
+
+def _stage_sites(
+    dbs: Sequence[TransactionDB], lists: list[list[Itemset]], live: list[int], w: int
+) -> tuple[jax.Array, jax.Array]:
+    """The live sites' tables and candidate masks, padded to one
+    ``(S, N, W)`` and one ``(S, C, W)`` array on the host and uploaded."""
+    n_max = max(dbs[i].n_tx for i in live)
+    c_max = _cand_bucket(max(len(lists[i]) for i in live))
+    tx_s = np.zeros((len(live), n_max, w), dtype=np.uint32)
+    masks_s = np.zeros((len(live), c_max, w), dtype=np.uint32)
+    for row, i in enumerate(live):
+        tx_s[row, : dbs[i].n_tx] = np.asarray(dbs[i].packed)
+        masks_s[row, : len(lists[i])] = pack_itemsets(lists[i], dbs[i].n_items)
+    return jnp.asarray(tx_s), jnp.asarray(masks_s)
 
 
 def fused_count_sites(
@@ -187,20 +217,15 @@ def fused_count_sites(
         for i in live:
             out[i] = count_supports(dbs[i], lists[i], backend=backend)
         return out
-    w = widths.pop()
-    n_max = max(dbs[i].n_tx for i in live)
-    c_max = _cand_bucket(max(len(lists[i]) for i in live))
-    tx_s = np.zeros((len(live), n_max, w), dtype=np.uint32)
-    masks_s = np.zeros((len(live), c_max, w), dtype=np.uint32)
-    for row, i in enumerate(live):
-        tx_s[row, : dbs[i].n_tx] = np.asarray(dbs[i].packed)
-        masks_s[row, : len(lists[i])] = pack_itemsets(lists[i], dbs[i].n_items)
-    if backend == "kernel":
-        from repro.kernels import ops
+    with span("repro.level.stage"):
+        tx_s, masks_s = _stage_sites(dbs, lists, live, widths.pop())
+    with span("repro.level.count"):
+        if backend == "kernel":
+            from repro.kernels import ops
 
-        counts = np.asarray(ops.support_count_sites(jnp.asarray(tx_s), jnp.asarray(masks_s)))
-    else:
-        counts = np.asarray(_count_block_sites(jnp.asarray(tx_s), jnp.asarray(masks_s)))
+            counts = np.asarray(ops.support_count_sites(tx_s, masks_s))
+        else:
+            counts = np.asarray(_count_block_sites(tx_s, masks_s))
     for row, i in enumerate(live):
         out[i] = counts[row, : len(lists[i])].astype(np.int64)
     return out
@@ -239,25 +264,18 @@ def fused_prune_sites(
         for i in live:
             out[i] = count_supports_prune(dbs[i], lists[i], min_counts[i], backend=backend)
         return out
-    w = widths.pop()
-    n_max = max(dbs[i].n_tx for i in live)
-    c_max = _cand_bucket(max(len(lists[i]) for i in live))
-    tx_s = np.zeros((len(live), n_max, w), dtype=np.uint32)
-    masks_s = np.zeros((len(live), c_max, w), dtype=np.uint32)
-    mc = np.asarray([int(min_counts[i]) for i in live], dtype=np.int32)
-    for row, i in enumerate(live):
-        tx_s[row, : dbs[i].n_tx] = np.asarray(dbs[i].packed)
-        masks_s[row, : len(lists[i])] = pack_itemsets(lists[i], dbs[i].n_items)
-    if backend == "kernel":
-        from repro.kernels import ops
+    with span("repro.level.stage"):
+        tx_s, masks_s = _stage_sites(dbs, lists, live, widths.pop())
+        mc = np.asarray([int(min_counts[i]) for i in live], dtype=np.int32)
+    with span("repro.level.count"):
+        if backend == "kernel":
+            from repro.kernels import ops
 
-        counts, freq = ops.support_count_prune_sites(
-            jnp.asarray(tx_s), jnp.asarray(masks_s), jnp.asarray(mc)
-        )
-        counts, freq = np.asarray(counts), np.asarray(freq)
-    else:
-        counts = np.asarray(_count_block_sites(jnp.asarray(tx_s), jnp.asarray(masks_s)))
-        freq = counts >= mc[:, None]
+            counts, freq = ops.support_count_prune_sites(tx_s, masks_s, jnp.asarray(mc))
+            counts, freq = np.asarray(counts), np.asarray(freq)
+        else:
+            counts = np.asarray(_count_block_sites(tx_s, masks_s))
+            freq = counts >= mc[:, None]
     for row, i in enumerate(live):
         c_i = len(lists[i])
         out[i] = (counts[row, :c_i].astype(np.int64), freq[row, :c_i])
@@ -266,10 +284,11 @@ def fused_prune_sites(
 
 def item_supports(db: TransactionDB) -> np.ndarray:
     """Singleton supports (L1 seed) via bit-unpack + column sum."""
-    words = np.asarray(db.packed)  # (N, W)
-    bits = ((words[:, :, None] >> np.arange(32, dtype=np.uint32)[None, None, :]) & 1).astype(np.int64)
-    cols = bits.reshape(words.shape[0], -1)[:, : db.n_items]
-    return cols.sum(axis=0)
+    with span("repro.level.count1"):
+        words = np.asarray(db.packed)  # (N, W)
+        bits = ((words[:, :, None] >> np.arange(32, dtype=np.uint32)[None, None, :]) & 1).astype(np.int64)
+        cols = bits.reshape(words.shape[0], -1)[:, : db.n_items]
+        return cols.sum(axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -279,23 +298,24 @@ def item_supports(db: TransactionDB) -> np.ndarray:
 
 def apriori_join(prev_frequent: Iterable[Itemset]) -> list[Itemset]:
     """F(k-1) x F(k-1) prefix join + downward-closure prune."""
-    prev = sorted(set(prev_frequent))
-    prev_set = set(prev)
-    if not prev:
-        return []
-    k_1 = len(prev[0])
-    out = []
-    for a_i in range(len(prev)):
-        a = prev[a_i]
-        for b_i in range(a_i + 1, len(prev)):
-            b = prev[b_i]
-            if a[:-1] != b[:-1]:
-                break  # sorted ⇒ shared prefix block is contiguous
-            cand = a + (b[-1],)
-            # prune: every (k)-subset must be in prev_set
-            if all(tuple(sub) in prev_set for sub in combinations(cand, k_1)):
-                out.append(cand)
-    return out
+    with span("repro.level.join"):
+        prev = sorted(set(prev_frequent))
+        prev_set = set(prev)
+        if not prev:
+            return []
+        k_1 = len(prev[0])
+        out = []
+        for a_i in range(len(prev)):
+            a = prev[a_i]
+            for b_i in range(a_i + 1, len(prev)):
+                b = prev[b_i]
+                if a[:-1] != b[:-1]:
+                    break  # sorted ⇒ shared prefix block is contiguous
+                cand = a + (b[-1],)
+                # prune: every (k)-subset must be in prev_set
+                if all(tuple(sub) in prev_set for sub in combinations(cand, k_1)):
+                    out.append(cand)
+        return out
 
 
 def subsets_of(itemset: Itemset) -> list[Itemset]:
@@ -333,9 +353,10 @@ def local_apriori(
     n_cand = 0
 
     sup1 = item_supports(db)
-    for item, c in enumerate(sup1):
-        counts[(int(item),)] = int(c)
-    frequent[1] = [(int(i),) for i in np.nonzero(sup1 >= min_count)[0]]
+    with span("repro.level.fold"):
+        for item, c in enumerate(sup1):
+            counts[(int(item),)] = int(c)
+        frequent[1] = [(int(i),) for i in np.nonzero(sup1 >= min_count)[0]]
     calls += 1
     n_cand += db.n_items
 
@@ -349,9 +370,10 @@ def local_apriori(
         sup, freq = count_supports_prune(db, cands, min_count, backend=backend)
         calls += 1
         n_cand += len(cands)
-        for its, c in zip(cands, sup):
-            counts[its] = int(c)
-        frequent[level] = [its for its, f in zip(cands, freq) if f]
+        with span("repro.level.fold"):
+            for its, c in zip(cands, sup):
+                counts[its] = int(c)
+            frequent[level] = [its for its, f in zip(cands, freq) if f]
     for lv in range(1, k_max + 1):
         frequent.setdefault(lv, [])
     return LocalMineResult(counts=counts, frequent=frequent, count_calls=calls, candidates_counted=n_cand)
@@ -385,16 +407,17 @@ def batched_local_apriori(
     for db, min_count in zip(dbs, min_counts):
         counts: dict[Itemset, int] = {}
         sup1 = item_supports(db)
-        for item, c in enumerate(sup1):
-            counts[(int(item),)] = int(c)
-        res.append(
-            LocalMineResult(
-                counts=counts,
-                frequent={1: [(int(i),) for i in np.nonzero(sup1 >= min_count)[0]]},
-                count_calls=1,
-                candidates_counted=db.n_items,
+        with span("repro.level.fold"):
+            for item, c in enumerate(sup1):
+                counts[(int(item),)] = int(c)
+            res.append(
+                LocalMineResult(
+                    counts=counts,
+                    frequent={1: [(int(i),) for i in np.nonzero(sup1 >= min_count)[0]]},
+                    count_calls=1,
+                    candidates_counted=db.n_items,
+                )
             )
-        )
     level = 1
     active = set(range(len(dbs)))
     while level < k_max and active:
@@ -406,18 +429,19 @@ def batched_local_apriori(
             cands_by[i] = apriori_join(res[i].frequent[level])
         level += 1
         sups = fused_prune_sites(dbs, cands_by, min_counts, backend=backend)
-        for i in list(active):
-            cands = cands_by[i]
-            if not cands:
-                res[i].frequent[level] = []
-                active.discard(i)
-                continue
-            res[i].count_calls += 1
-            res[i].candidates_counted += len(cands)
-            cnt_i, freq_i = sups[i]
-            for its, c in zip(cands, cnt_i):
-                res[i].counts[its] = int(c)
-            res[i].frequent[level] = [its for its, f in zip(cands, freq_i) if f]
+        with span("repro.level.fold"):
+            for i in list(active):
+                cands = cands_by[i]
+                if not cands:
+                    res[i].frequent[level] = []
+                    active.discard(i)
+                    continue
+                res[i].count_calls += 1
+                res[i].candidates_counted += len(cands)
+                cnt_i, freq_i = sups[i]
+                for its, c in zip(cands, cnt_i):
+                    res[i].counts[its] = int(c)
+                res[i].frequent[level] = [its for its, f in zip(cands, freq_i) if f]
     for lm in res:
         for lv in range(1, k_max + 1):
             lm.frequent.setdefault(lv, [])
@@ -494,8 +518,9 @@ class DeltaApriori:
         st = cls(db.n_items, backend=backend)
         sup1 = item_supports(db)
         st.count_calls += 1
-        for item, c in enumerate(sup1):
-            st._counts[(int(item),)] += int(c)
+        with span("repro.level.fold"):
+            for item, c in enumerate(sup1):
+                st._counts[(int(item),)] += int(c)
         st._batches.append(db)
         st._full = db
         st.version = 1
@@ -526,16 +551,18 @@ class DeltaApriori:
         if not itemsets:
             return
         self.count_calls += 1
-        for its, c in zip(itemsets, counts):
-            self._counts[its] = int(c)
+        with span("repro.level.fold"):
+            for its, c in zip(itemsets, counts):
+                self._counts[its] = int(c)
 
     def counts_for(self, itemsets: Sequence[Itemset]) -> dict[Itemset, int]:
         """Exact cumulative counts for arbitrary itemsets, counting only
         the never-seen ones (at most one device pass); cached itemsets are
         served for free — the local-pass entry point for workloads that
         bring their own candidate lists (count-distribution Apriori)."""
-        self._count_new(self.uncached(itemsets))
-        return {its: self._counts[its] for its in itemsets}
+        with span("repro.level.fold"):
+            self._count_new(self.uncached(itemsets))
+            return {its: self._counts[its] for its in itemsets}
 
     def append(self, dense_batch: np.ndarray) -> int:
         """Fold one appended transaction batch into the cumulative counts

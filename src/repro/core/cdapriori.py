@@ -35,7 +35,6 @@ seam carries a whole new mining app.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 
 import numpy as np
@@ -48,6 +47,7 @@ from repro.core.apriori import (
     fused_count_sites,
 )
 from repro.core.gfm import CommLog, _itemset_bytes
+from repro.obs import span
 
 
 @dataclass
@@ -134,11 +134,11 @@ def cd_site_jobs(
     job's result is a ``CDAprioriResult`` equal to ``cd_mine``'s.
 
     Same multihost discipline as ``fdm_site_jobs``: per-site jobs are
-    closure-pure toward the SHARED ledger (their device-pass flags and
-    timings travel in their results; only the sync jobs fold into the
-    CommLog).  Each site's per-level ``DeltaApriori`` state is mutated
-    only by that site's own count jobs, which the ownership map pins to
-    one process for the whole run.  Run without fault injection (a
+    closure-pure toward the SHARED ledger (their device-pass flags travel
+    in their results; only the sync jobs fold into the CommLog).  Each
+    site's per-level ``DeltaApriori`` state is mutated only by that
+    site's own count jobs, which the ownership map pins to one process
+    for the whole run.  Run without fault injection (a
     retried sync job would ledger twice).
 
     The ``count_l_*`` fan-out carries ``batch_key``/``batched_fn``: under
@@ -170,15 +170,13 @@ def cd_site_jobs(
             if level > 1 and (prev is None or not prev["global"]):
                 return None  # search exhausted at an earlier level
             cands = _level_candidates(level, n_items, prev["global"] if prev else [])
-            t0 = time.perf_counter()
             st = _state(i)
             # passes: device invocations this level, as cd_mine ledgers
             # them — the level-1 singleton seed, or one pass over the
             # never-seen candidates
             passes = 1 if level == 1 else (1 if st.uncached(cands) else 0)
             cnt = st.counts_for(cands)
-            return {"cands": cands, "cnt": cnt, "t": time.perf_counter() - t0,
-                    "passes": passes}
+            return {"cands": cands, "cnt": cnt, "passes": passes}
 
         return fn
 
@@ -202,24 +200,22 @@ def cd_site_jobs(
             outs: list[dict | None] = [None] * len(bargs)
             if not live:
                 return outs
-            t0 = time.perf_counter()
             cands_by = [
                 _level_candidates(level, n_items, prevs[j]["global"] if prevs[j] else [])
                 for j in live
             ]
             sts = [bargs[j][1](bargs[j][0]) for j in live]
-            missing_by = [st.uncached(cands) for st, cands in zip(sts, cands_by)]
+            with span("repro.level.stage"):
+                missing_by = [st.uncached(cands) for st, cands in zip(sts, cands_by)]
             if any(missing_by):
                 sups = fused_count_sites(
                     [st.stream() for st in sts], missing_by, backend=backend
                 )
                 for st, missing, sup in zip(sts, missing_by, sups):
                     st.fold_exact(missing, sup)
-            share = (time.perf_counter() - t0) / max(len(live), 1)
             for j, st, cands, missing in zip(live, sts, cands_by, missing_by):
                 passes = 1 if level == 1 else (1 if missing else 0)
-                outs[j] = {"cands": cands, "cnt": st.counts_for(cands),
-                           "t": share, "passes": passes}
+                outs[j] = {"cands": cands, "cnt": st.counts_for(cands), "passes": passes}
             return outs
 
         return fused
@@ -228,15 +224,16 @@ def cd_site_jobs(
         def fn(*outs):
             if any(o is None for o in outs):
                 return None  # search exhausted (all-or-nothing per level)
-            cands = outs[0]["cands"]
-            per_level.append(len(cands))
-            if not cands:
-                return None
-            comm.count_calls += sum(o["passes"] for o in outs)
-            comm.add_round(len(cands) * s, _itemset_bytes(level), s)
-            totals = {its: sum(o["cnt"][its] for o in outs) for its in cands}
-            glob = [(its, c) for its, c in totals.items() if c >= g_min]
-            return {"global": [its for its, _ in glob], "frequent": dict(glob)}
+            with span("repro.sync"):
+                cands = outs[0]["cands"]
+                per_level.append(len(cands))
+                if not cands:
+                    return None
+                comm.count_calls += sum(o["passes"] for o in outs)
+                comm.add_round(len(cands) * s, _itemset_bytes(level), s)
+                totals = {its: sum(o["cnt"][its] for o in outs) for its in cands}
+                glob = [(its, c) for its, c in totals.items() if c >= g_min]
+                return {"global": [its for its, _ in glob], "frequent": dict(glob)}
 
         return fn
 
@@ -265,9 +262,10 @@ def cd_site_jobs(
 
     def collect_fn(*decisions):
         frequent: dict[Itemset, int] = {}
-        for dec in decisions:
-            if dec is not None:
-                frequent.update(dec["frequent"])
+        with span("repro.sync"):
+            for dec in decisions:
+                if dec is not None:
+                    frequent.update(dec["frequent"])
         return CDAprioriResult(
             frequent=frequent,
             comm=comm,

@@ -31,6 +31,7 @@ from repro.core.apriori import (
     item_supports,
 )
 from repro.core.gfm import CommLog, _itemset_bytes
+from repro.obs import span
 
 
 @dataclass
@@ -212,8 +213,9 @@ def fdm_site_jobs(
             dt = time.perf_counter() - t0
             # counted: a real device invocation, as fdm_mine ledgers it
             counted = level == 1 or bool(cands)
-            cnt = {its: int(c) for its, c in zip(cands, np.asarray(sup))}
-            ann = {its for its in cands if cnt[its] >= l_min[i]}
+            with span("repro.level.fold"):
+                cnt = {its: int(c) for its, c in zip(cands, np.asarray(sup))}
+                ann = {its for its in cands if cnt[its] >= l_min[i]}
             return {"cnt": cnt, "ann": ann, "t": dt, "counted": counted}
 
         return fn
@@ -254,15 +256,16 @@ def fdm_site_jobs(
                     [sites[bargs[j][0]] for j in live], cands_by, backend=backend
                 )
             share = (time.perf_counter() - t0) / max(len(live), 1)
-            for j, cands, sup in zip(live, cands_by, sups):
-                _i, lmin = bargs[j]
-                cnt = {its: int(c) for its, c in zip(cands, np.asarray(sup))}
-                outs[j] = {
-                    "cnt": cnt,
-                    "ann": {its for its in cands if cnt[its] >= lmin},
-                    "t": share,
-                    "counted": level == 1 or bool(cands),
-                }
+            with span("repro.level.fold"):
+                for j, cands, sup in zip(live, cands_by, sups):
+                    _i, lmin = bargs[j]
+                    cnt = {its: int(c) for its, c in zip(cands, np.asarray(sup))}
+                    outs[j] = {
+                        "cnt": cnt,
+                        "ann": {its for its in cands if cnt[its] >= lmin},
+                        "t": share,
+                        "counted": level == 1 or bool(cands),
+                    }
             return outs
 
         return fused
@@ -271,20 +274,21 @@ def fdm_site_jobs(
         def fn(*outs):
             if any(o is None for o in outs):
                 return None  # search exhausted (all-or-nothing per level)
-            union_cands = set()
-            announced = set()
-            payload = 0
-            for o in outs:
-                union_cands.update(o["cnt"].keys())
-                announced.update(o["ann"])
-                payload += len(o["ann"])
-            per_level.append(len(union_cands))
-            if not union_cands:
-                return None
-            return {
-                "announced": sorted(announced, key=lambda t: (len(t), t)),
-                "payload": payload,
-            }
+            with span("repro.sync"):
+                union_cands = set()
+                announced = set()
+                payload = 0
+                for o in outs:
+                    union_cands.update(o["cnt"].keys())
+                    announced.update(o["ann"])
+                    payload += len(o["ann"])
+                per_level.append(len(union_cands))
+                if not union_cands:
+                    return None
+                return {
+                    "announced": sorted(announced, key=lambda t: (len(t), t)),
+                    "payload": payload,
+                }
 
         return fn
 
@@ -294,14 +298,16 @@ def fdm_site_jobs(
         def fn(cout, ann):
             if cout is None or ann is None:
                 return None
-            remote = [its for its in ann["announced"] if its not in cout["cnt"]]
+            with span("repro.level.stage"):
+                remote = [its for its in ann["announced"] if its not in cout["cnt"]]
             dt = 0.0
             if remote:
                 t0 = time.perf_counter()
                 sup = count_supports(db, remote, backend=backend)
                 dt = time.perf_counter() - t0
-                for its, c in zip(remote, np.asarray(sup)):
-                    cout["cnt"][its] = int(c)
+                with span("repro.level.fold"):
+                    for its, c in zip(remote, np.asarray(sup)):
+                        cout["cnt"][its] = int(c)
             # carry this site's count-phase ledger entries forward — the
             # downstream decide job folds them into the shared CommLog
             return {
@@ -327,26 +333,28 @@ def fdm_site_jobs(
             outs: list[dict | None] = [None] * len(bargs)
             if not live:
                 return outs
-            remote_by = [
-                [its for its in argss[j][1]["announced"] if its not in argss[j][0]["cnt"]]
-                for j in live
-            ]
+            with span("repro.level.stage"):
+                remote_by = [
+                    [its for its in argss[j][1]["announced"] if its not in argss[j][0]["cnt"]]
+                    for j in live
+                ]
             t0 = time.perf_counter()
             sups = fused_count_sites([sites[bargs[j]] for j in live], remote_by, backend=backend)
             dt = time.perf_counter() - t0 if any(remote_by) else 0.0
             share = dt / max(sum(1 for r in remote_by if r), 1)
-            for j, remote, sup in zip(live, remote_by, sups):
-                cout = argss[j][0]
-                if remote:
-                    for its, c in zip(remote, np.asarray(sup)):
-                        cout["cnt"][its] = int(c)
-                outs[j] = {
-                    "cnt": cout["cnt"],
-                    "n_remote": len(remote),
-                    "count_t": cout["t"],
-                    "count_counted": cout["counted"],
-                    "remote_t": share if remote else 0.0,
-                }
+            with span("repro.level.fold"):
+                for j, remote, sup in zip(live, remote_by, sups):
+                    cout = argss[j][0]
+                    if remote:
+                        for its, c in zip(remote, np.asarray(sup)):
+                            cout["cnt"][its] = int(c)
+                    outs[j] = {
+                        "cnt": cout["cnt"],
+                        "n_remote": len(remote),
+                        "count_t": cout["t"],
+                        "count_counted": cout["counted"],
+                        "remote_t": share if remote else 0.0,
+                    }
             return outs
 
         return fused
@@ -355,33 +363,34 @@ def fdm_site_jobs(
         def fn(ann, *remotes):
             if ann is None:
                 return None
-            # ann non-None implies every count (and hence remote) is live,
-            # so remotes[i] is site i's counts — positional, no filtering.
-            # The per-site device-invocation flags shipped with the remote
-            # results are ledgered HERE (one +1 per real count call, as
-            # fdm_mine counts them): counts first, then remote serves.
-            comm.count_calls += sum(1 for r in remotes if r["count_counted"])
-            comm.count_calls += sum(1 for r in remotes if r["n_remote"])
-            comm.add_round(
-                ann["payload"] + sum(r["n_remote"] for r in remotes), _itemset_bytes(level), s
-            )
-            glob = []
-            for its in ann["announced"]:
-                c = sum(r["cnt"].get(its, 0) for r in remotes)
-                if c >= g_min:
-                    glob.append((its, c))
-            prev_global = [its for its, _ in glob]
-            prev_local = [
-                {its for its in prev_global if remotes[i]["cnt"].get(its, 0) >= l_min[i]}
-                for i in range(s)
-            ]
-            return {
-                "global": prev_global,
-                "local": prev_local,
-                "frequent": dict(glob),
-                "count_t": sum(r["count_t"] for r in remotes),
-                "remote_t": sum(r["remote_t"] for r in remotes),
-            }
+            with span("repro.sync"):
+                # ann non-None implies every count (and hence remote) is live,
+                # so remotes[i] is site i's counts — positional, no filtering.
+                # The per-site device-invocation flags shipped with the remote
+                # results are ledgered HERE (one +1 per real count call, as
+                # fdm_mine counts them): counts first, then remote serves.
+                comm.count_calls += sum(1 for r in remotes if r["count_counted"])
+                comm.count_calls += sum(1 for r in remotes if r["n_remote"])
+                comm.add_round(
+                    ann["payload"] + sum(r["n_remote"] for r in remotes), _itemset_bytes(level), s
+                )
+                glob = []
+                for its in ann["announced"]:
+                    c = sum(r["cnt"].get(its, 0) for r in remotes)
+                    if c >= g_min:
+                        glob.append((its, c))
+                prev_global = [its for its, _ in glob]
+                prev_local = [
+                    {its for its in prev_global if remotes[i]["cnt"].get(its, 0) >= l_min[i]}
+                    for i in range(s)
+                ]
+                return {
+                    "global": prev_global,
+                    "local": prev_local,
+                    "frequent": dict(glob),
+                    "count_t": sum(r["count_t"] for r in remotes),
+                    "remote_t": sum(r["remote_t"] for r in remotes),
+                }
 
         return fn
 
@@ -432,11 +441,12 @@ def fdm_site_jobs(
         frequent: dict[Itemset, int] = {}
         remote_t = 0.0
         total_t = 0.0
-        for dec in decisions:
-            if dec is not None:
-                frequent.update(dec["frequent"])
-                remote_t += dec["remote_t"]
-                total_t += dec["count_t"] + dec["remote_t"]
+        with span("repro.sync"):
+            for dec in decisions:
+                if dec is not None:
+                    frequent.update(dec["frequent"])
+                    remote_t += dec["remote_t"]
+                    total_t += dec["count_t"] + dec["remote_t"]
         return FDMResult(
             frequent=frequent,
             comm=comm,

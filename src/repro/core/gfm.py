@@ -41,6 +41,7 @@ from repro.core.apriori import (
     local_apriori,
     subsets_of,
 )
+from repro.obs import span
 
 
 @dataclass
@@ -100,11 +101,13 @@ def fill_missing(
     """Phase 2 pass 2, one site's share: count the pool entries this site
     had NOT already counted locally.  Mutates ``lm.counts`` (idempotent —
     re-running counts nothing) and returns the number counted."""
-    missing = [its for its in pool if its not in lm.counts]
+    with span("repro.level.stage"):
+        missing = [its for its in pool if its not in lm.counts]
     if missing:
         sup = count_supports(db, missing, backend=backend)
-        for its, c in zip(missing, sup):
-            lm.counts[its] = int(c)
+        with span("repro.level.fold"):
+            for its, c in zip(missing, sup):
+                lm.counts[its] = int(c)
     return len(missing)
 
 
@@ -296,12 +299,13 @@ def gfm_site_jobs(
         )
 
     def pool_fn(*local):
-        for lm in local:
-            comm.count_calls += lm.count_calls
-        pool, payload = build_pool(list(local), k)
-        comm.add_round(payload, _itemset_bytes(k), s)
-        pool_sizes.append(len(pool))
-        return pool
+        with span("repro.sync"):
+            for lm in local:
+                comm.count_calls += lm.count_calls
+            pool, payload = build_pool(list(local), k)
+            comm.add_round(payload, _itemset_bytes(k), s)
+            pool_sizes.append(len(pool))
+            return pool
 
     jobs.append(
         SiteJob(
@@ -333,14 +337,16 @@ def gfm_site_jobs(
         # the same pool object, but a cross-request merged wave (service
         # fusion) has one pool per request, so the pool must come from
         # each member's argss entry, never from member 0's
-        missing_by = [[its for its in pool if its not in lm.counts] for lm, pool in argss]
+        with span("repro.level.stage"):
+            missing_by = [[its for its in pool if its not in lm.counts] for lm, pool in argss]
         sups = fused_count_sites([sites[i] for i in bargs], missing_by, backend=backend)
         outs = []
-        for (lm, _pool), missing, sup in zip(argss, missing_by, sups):
-            if missing:
-                for its, c in zip(missing, sup):
-                    lm.counts[its] = int(c)
-            outs.append((lm, len(missing)))
+        with span("repro.level.fold"):
+            for (lm, _pool), missing, sup in zip(argss, missing_by, sups):
+                if missing:
+                    for its, c in zip(missing, sup):
+                        lm.counts[its] = int(c)
+                outs.append((lm, len(missing)))
         return outs
 
     for i in range(s):
@@ -357,19 +363,21 @@ def gfm_site_jobs(
         )
 
     def decide_fn(pool, *recounts):
-        local = [lm for lm, _ in recounts]
-        # each site that actually had missing pool entries made one device
-        # count call during its recount — ledgered HERE, from the shipped
-        # results, exactly as gfm_mine counts it
-        comm.count_calls += sum(1 for _, nm in recounts if nm)
-        comm.add_round(sum(nm for _, nm in recounts), _itemset_bytes(k), s)
-        counts = aggregate_counts(pool, local)
-        decided = {its: (c, c >= g_min) for its, c in counts.items()}
-        topdown_search(sites, local, decided, g_min, comm, k, backend, pool_sizes)
-        frequent = {its: c for its, (c, ok) in decided.items() if ok}
-        return GFMResult(
-            frequent=frequent, comm=comm, local=local, pool_sizes=pool_sizes, n_total_tx=n_total
-        )
+        with span("repro.sync"):
+            local = [lm for lm, _ in recounts]
+            # each site that actually had missing pool entries made one device
+            # count call during its recount — ledgered HERE, from the shipped
+            # results, exactly as gfm_mine counts it
+            comm.count_calls += sum(1 for _, nm in recounts if nm)
+            comm.add_round(sum(nm for _, nm in recounts), _itemset_bytes(k), s)
+            counts = aggregate_counts(pool, local)
+            decided = {its: (c, c >= g_min) for its, c in counts.items()}
+            topdown_search(sites, local, decided, g_min, comm, k, backend, pool_sizes)
+            frequent = {its: c for its, (c, ok) in decided.items() if ok}
+            return GFMResult(
+                frequent=frequent, comm=comm, local=local, pool_sizes=pool_sizes,
+                n_total_tx=n_total,
+            )
 
     jobs.append(
         SiteJob(

@@ -34,8 +34,12 @@ arXiv:1412.2673) is a bursty stream of arrivals from many users.
     (``runtime.cache.ResultCache``); any append bumps the version, so a
     stale result is unreachable by key construction.
   * **ledger** — per-request and per-tenant records (queue wait, compute
-    share, cache hit, backend used) in the same spirit as the engine's
+    share, cache hit, backend used, XLA compiles made while the request's
+    execution group ran) in the same spirit as the engine's
     ``RunReport``, JSON-serializable for the CI smoke's artifact.
+  * **spans** — ``repro.step`` (one tick), ``repro.request`` (one
+    execution group or fused bucket) and ``repro.split`` (the site split)
+    on the profiler's clock (``repro.obs``); see ``docs/serving.md``.
 
 CLI driver (bursty synthetic multi-tenant trace; exits non-zero when any
 request failed; ``--check`` also gates the fairness bound, cache hits,
@@ -52,6 +56,7 @@ import json
 import sys
 import time
 from collections import OrderedDict, deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -60,6 +65,7 @@ import numpy as np
 from repro.core.apriori import DeltaApriori
 from repro.data.synthetic import gaussian_mixture, ibm_transactions
 from repro.launch.mesh import enable_compile_cache
+from repro.obs import compiles, span
 from repro.runtime.cache import ResultCache, params_key
 from repro.runtime.gridruntime import GridRuntime
 from repro.workflow.registry import app_names, get_workload, workloads
@@ -166,6 +172,10 @@ class MiningService:
         # dataset version)
         self.failures = 0
         self.failure_memo_hits = 0
+        # XLA compiles (or loads from the persistent compile cache) made
+        # while execution groups ran, and their seconds
+        self.compiles = 0
+        self.compile_s = 0.0
         self._failure_memo: OrderedDict[tuple, str] = OrderedDict()
         self._failure_memo_cap = int(failure_memo_capacity)
         # tenant pick order, for the fairness audit (CI gates a prefix
@@ -302,41 +312,42 @@ class MiningService:
         groups run as ONE fused device dispatch, everything else runs
         serially per group.  Returns the ids completed (done or failed)
         this wave."""
-        batch = self.queues.pick_batch(max_requests)
-        now = self._clock()
-        for req in batch:
-            req.status = "running"
-            req.started_at = now
-            req.dataset_version = self._datasets[req.dataset].version
-            self.pick_log.append(req.tenant)
-        finished: list[int] = []
-        pending: list[tuple[tuple, tuple, list[MiningRequest]]] = []
-        for ekey, reqs in coalesce(batch, self._exec_key).items():
-            rep = reqs[0]
-            for other in reqs[1:]:
-                other.coalesced_into = rep.request_id
-            self.coalesced += len(reqs) - 1
-            ckey = ResultCache.key(rep.dataset, rep.dataset_version, rep.app, rep.params)
-            value = self.cache.get(ckey)
-            if value is not None:
-                self._finish(reqs, value, compute_s=0.0, backend="cache", cache_hit=True)
-                finished.extend(r.request_id for r in reqs)
-                continue
-            memo_err = self._failure_memo.get(ekey)
-            if memo_err is not None:
-                # a deterministically-failing request resubmitted by a
-                # polling tenant short-circuits here instead of paying a
-                # full grid run every wave; the memo key includes the
-                # dataset version, so any append retries for real
-                self.failure_memo_hits += 1
-                self._fail(reqs, memo_err, backend="failure-memo")
-                finished.extend(r.request_id for r in reqs)
-                continue
-            pending.append((ekey, ckey, reqs))
-        self.exec_groups += len(pending)
-        for bucket in self._fuse_buckets(pending):
-            finished.extend(self._run_bucket(bucket))
-        return finished
+        with span("repro.step"):
+            batch = self.queues.pick_batch(max_requests)
+            now = self._clock()
+            for req in batch:
+                req.status = "running"
+                req.started_at = now
+                req.dataset_version = self._datasets[req.dataset].version
+                self.pick_log.append(req.tenant)
+            finished: list[int] = []
+            pending: list[tuple[tuple, tuple, list[MiningRequest]]] = []
+            for ekey, reqs in coalesce(batch, self._exec_key).items():
+                rep = reqs[0]
+                for other in reqs[1:]:
+                    other.coalesced_into = rep.request_id
+                self.coalesced += len(reqs) - 1
+                ckey = ResultCache.key(rep.dataset, rep.dataset_version, rep.app, rep.params)
+                value = self.cache.get(ckey)
+                if value is not None:
+                    self._finish(reqs, value, compute_s=0.0, backend="cache", cache_hit=True)
+                    finished.extend(r.request_id for r in reqs)
+                    continue
+                memo_err = self._failure_memo.get(ekey)
+                if memo_err is not None:
+                    # a deterministically-failing request resubmitted by a
+                    # polling tenant short-circuits here instead of paying a
+                    # full grid run every wave; the memo key includes the
+                    # dataset version, so any append retries for real
+                    self.failure_memo_hits += 1
+                    self._fail(reqs, memo_err, backend="failure-memo")
+                    finished.extend(r.request_id for r in reqs)
+                    continue
+                pending.append((ekey, ckey, reqs))
+            self.exec_groups += len(pending)
+            for bucket in self._fuse_buckets(pending):
+                finished.extend(self._run_bucket(bucket))
+            return finished
 
     def drain(self, max_requests: int = 8, max_steps: int | None = None) -> list[int]:
         """Step until every queue is empty (or ``max_steps``); returns all
@@ -447,18 +458,37 @@ class MiningService:
                 continue
             t0 = self._clock()
             self.device_dispatches += 1
-            try:
-                value, compute_s, backend = self._execute(rep)
-            except Exception as e:  # noqa: BLE001 — one bad request must not kill the service
-                err = f"{type(e).__name__}: {e}"
-                self._memo_failure(ekey, err)
-                self._fail(reqs, err, backend=self.backend_name,
-                           attempt_s=self._clock() - t0)
-                finished.extend(r.request_id for r in reqs)
-                continue
-            self._complete_group(ckey, reqs, value, compute_s, backend, fused=False)
+            with self._request_span(reqs):
+                try:
+                    value, compute_s, backend = self._execute(rep)
+                except Exception as e:  # noqa: BLE001 — one bad request must not kill the service
+                    err = f"{type(e).__name__}: {e}"
+                    self._memo_failure(ekey, err)
+                    self._fail(reqs, err, backend=self.backend_name,
+                               attempt_s=self._clock() - t0)
+                    finished.extend(r.request_id for r in reqs)
+                    continue
+                self._complete_group(ckey, reqs, value, compute_s, backend, fused=False)
             finished.extend(r.request_id for r in reqs)
         return finished
+
+    @contextmanager
+    def _request_span(self, reqs: list[MiningRequest]):
+        """The ``repro.request`` span around one execution group or one
+        fused bucket: the compiles made inside it are added to each of
+        ``reqs`` and to the service's totals, and recorded on the span."""
+        c0 = compiles()
+        ids = " ".join(str(r.request_id) for r in reqs)
+        with span("repro.request", app=reqs[0].app, request_ids=ids) as sp:
+            try:
+                yield
+            finally:
+                made = compiles() - c0
+                self.compiles += made.count
+                self.compile_s += made.seconds
+                for req in reqs:
+                    req.compiles += made.count
+                sp.set_metadata(compiles=made.count)
 
     def _complete_group(
         self, ckey, reqs, value, compute_s: float, backend: str, *, fused: bool,
@@ -487,20 +517,22 @@ class MiningService:
         spec = get_workload(reps[0].app)
         ds = self._datasets[reps[0].dataset]
         self.device_dispatches += 1
-        if spec.runner == "grid":
-            datas, plists = [], []
-            for rep in reps:
-                p = spec.resolve(rep.params)
-                datas.append(spec.site_split(ds, p, self))
-                plists.append(spec.grid_params(p, self))
-            runs = self.runtime.run_many(reps[0].app, datas, plists)
-            values = [(r.result, r.compute_s, r.backend) for r in runs]
-        else:
-            values = self._run_many_local(reps, spec, ds)
         finished: list[int] = []
-        for (_ekey, ckey, reqs), (value, compute_s, backend) in zip(bucket, values):
-            self._complete_group(ckey, reqs, value, compute_s, backend, fused=True)
-            finished.extend(r.request_id for r in reqs)
+        with self._request_span([r for _, _, reqs in bucket for r in reqs]):
+            if spec.runner == "grid":
+                datas, plists = [], []
+                for rep in reps:
+                    p = spec.resolve(rep.params)
+                    with span("repro.split"):
+                        datas.append(spec.site_split(ds, p, self))
+                    plists.append(spec.grid_params(p, self))
+                runs = self.runtime.run_many(reps[0].app, datas, plists)
+                values = [(r.result, r.compute_s, r.backend) for r in runs]
+            else:
+                values = self._run_many_local(reps, spec, ds)
+            for (_ekey, ckey, reqs), (value, compute_s, backend) in zip(bucket, values):
+                self._complete_group(ckey, reqs, value, compute_s, backend, fused=True)
+                finished.extend(r.request_id for r in reqs)
         return finished
 
     def _run_many_local(self, reps, spec, ds) -> list[tuple[Any, float, str]]:
@@ -549,7 +581,8 @@ class MiningService:
             if spec.finalize is not None:
                 spec.finalize(ds, p, value)
             return value, compute_s, backend
-        data = spec.site_split(ds, p, self)
+        with span("repro.split"):
+            data = spec.site_split(ds, p, self)
         run = self.runtime.run(req.app, data, spec.grid_params(p, self))
         return run.result, run.report.compute_s, run.backend
 
@@ -581,6 +614,8 @@ class MiningService:
             "fused_fallback_errors": list(self.fused_fallback_errors),
             "failures": self.failures,
             "failure_memo_hits": self.failure_memo_hits,
+            "compiles": self.compiles,
+            "compile_s": self.compile_s,
             "rejected": self.queues.rejected + self.invalid,
             "rejected_full": self.rejected_full,
             "rejected_invalid": self.invalid,
@@ -634,6 +669,7 @@ class MiningService:
             "queue_wait_s": req.queue_wait_s,
             "compute_s": req.compute_s,
             "service_s": req.service_s,
+            "compiles": req.compiles,
             "error": req.error,
         }
 
@@ -786,6 +822,7 @@ def main(argv=None) -> int:
           f"fused_requests={led['fused_requests']} "
           f"fused_fallbacks={led['fused_fallbacks']} "
           f"failures={led['failures']} memo_hits={led['failure_memo_hits']}")
+    print(f"[serve] compiles={led['compiles']} compile_s={led['compile_s']:.3f}")
     print(f"[serve] throughput={len(done) / max(wall, 1e-9):.1f} req/s "
           f"latency p50={np.percentile(lat, 50) * 1e3:.1f}ms "
           f"p95={np.percentile(lat, 95) * 1e3:.1f}ms")

@@ -34,6 +34,7 @@ from repro.core.vclustering import (
     merge_gathered,
 )
 from repro.launch.mesh import make_site_mesh
+from repro.obs import span
 from repro.workflow.registry import RunContext, get_workload
 from repro.workflow.engine import Engine, RunReport
 from repro.workflow.executor import ExecutionBackend
@@ -304,7 +305,8 @@ class GridRuntime:
             use_kernel=self.use_kernel,
             cluster_sync=self._cluster_sync,
         )
-        jobs, mode = spec.build_jobs(data, p, ctx)
+        with span("repro.build"):
+            jobs, mode = spec.build_jobs(data, p, ctx)
         rep, results = self.engine.run_site_jobs(jobs, name=spec.name)
         return self._finish_run(jobs, rep, results[spec.terminal], measured, mode)
 
@@ -350,7 +352,8 @@ class GridRuntime:
                 use_kernel=self.use_kernel,
                 cluster_sync=self._cluster_sync,
             )
-            jobs, mode = spec.build_jobs(data, p, ctx)
+            with span("repro.build"):
+                jobs, mode = spec.build_jobs(data, p, ctx)
             modes.append(mode)
             prefix = f"r{j}/"
             for job in jobs:

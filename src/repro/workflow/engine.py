@@ -60,6 +60,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from pathlib import Path
 
+from repro.obs import span
 from repro.workflow.dag import DAG, Job, TimedResult
 from repro.workflow.executor import ExecutionBackend, resolve_backend
 from repro.workflow.faults import FaultInjector
@@ -207,52 +208,53 @@ class Engine:
         placement: str | PlacementPolicy | None = None,
         backend: str | ExecutionBackend | None = None,
     ) -> RunReport:
-        schedule = schedule or self.schedule
-        if schedule not in SCHEDULES:
-            raise ValueError(f"unknown schedule {schedule!r}; expected one of {SCHEDULES}")
-        policy = resolve_placement(placement if placement is not None else self.placement)
-        policy.reset()  # per-run state (RNG, round-robin cursor)
-        self._backend = resolve_backend(backend) if backend is not None else self.backend
-        dag.validate_acyclic()
-        rep = RunReport(schedule=schedule, placement=policy.name, backend=self._backend.name)
-        results = results if results is not None else {}
-        self._backend.begin_run(dag, results)
-        # multi-host ownership: a distributed backend partitions the DAG's
-        # sites over its processes (the model is passed so a backend can
-        # derive per-site load weights from it); the engine keeps
-        # scheduling EVERY job — the simulated clock/ledger must stay
-        # globally consistent — but only owned jobs execute here, the
-        # rest arrive as shipped results
-        self._partition = self._backend.partition(dag, self.model)
-        if self._partition is not None:
-            rep.n_processes = self._partition.n_processes
-            rep.process_index = self._partition.process_index
-            rep.owned_jobs = tuple(sorted(self._partition.owned))
-            rep.owned_sites = tuple(self._partition.owned_sites)
+        with span("repro.engine"):
+            schedule = schedule or self.schedule
+            if schedule not in SCHEDULES:
+                raise ValueError(f"unknown schedule {schedule!r}; expected one of {SCHEDULES}")
+            policy = resolve_placement(placement if placement is not None else self.placement)
+            policy.reset()  # per-run state (RNG, round-robin cursor)
+            self._backend = resolve_backend(backend) if backend is not None else self.backend
+            dag.validate_acyclic()
+            rep = RunReport(schedule=schedule, placement=policy.name, backend=self._backend.name)
+            results = results if results is not None else {}
+            self._backend.begin_run(dag, results)
+            # multi-host ownership: a distributed backend partitions the DAG's
+            # sites over its processes (the model is passed so a backend can
+            # derive per-site load weights from it); the engine keeps
+            # scheduling EVERY job — the simulated clock/ledger must stay
+            # globally consistent — but only owned jobs execute here, the
+            # rest arrive as shipped results
+            self._partition = self._backend.partition(dag, self.model)
+            if self._partition is not None:
+                rep.n_processes = self._partition.n_processes
+                rep.process_index = self._partition.process_index
+                rep.owned_jobs = tuple(sorted(self._partition.owned))
+                rep.owned_sites = tuple(self._partition.owned_sites)
 
-        # workflow preparation (the 295 s DAGMan latency).  With
-        # overlap_prep the first stage's submission pipeline hides all but
-        # a fixed connection setup.
-        prep = self.model.prep_latency_s
-        if self.overlap_prep:
-            prep = min(prep, 10.0)
-        rep.prep_s = prep
+            # workflow preparation (the 295 s DAGMan latency).  With
+            # overlap_prep the first stage's submission pipeline hides all but
+            # a fixed connection setup.
+            prep = self.model.prep_latency_s
+            if self.overlap_prep:
+                prep = min(prep, 10.0)
+            rep.prep_s = prep
 
-        done = self._load_rescue(dag)
-        for name in done:
-            if name in dag.jobs:
-                dag.jobs[name].status = "done"
+            done = self._load_rescue(dag)
+            for name in done:
+                if name in dag.jobs:
+                    dag.jobs[name].status = "done"
 
-        if schedule == "async":
-            self._run_async(dag, results, rep, done, policy)
-        else:
-            self._run_staged(dag, results, rep, done, policy)
-        led = self._backend.ledger()
-        if led is not None:
-            rep.shipments = int(led.get("shipments", 0))
-            rep.collective_rounds = int(led.get("collective_rounds", 0))
-            rep.shipped_results = int(led.get("shipped_results", 0))
-        return rep
+            if schedule == "async":
+                self._run_async(dag, results, rep, done, policy)
+            else:
+                self._run_staged(dag, results, rep, done, policy)
+            led = self._backend.ledger()
+            if led is not None:
+                rep.shipments = int(led.get("shipments", 0))
+                rep.collective_rounds = int(led.get("collective_rounds", 0))
+                rep.shipped_results = int(led.get("shipped_results", 0))
+            return rep
 
     # -- matchmaking ----------------------------------------------------------
 
@@ -655,7 +657,8 @@ class Engine:
             # the execution backend decides HOW fn runs (inline dispatch,
             # fused batch, multihost mesh); scheduling semantics around it
             # — faults, retries, rescue, the simulated clock — are ours
-            raw = self._backend.call(job, args)
+            with span("repro.job", job=job.name):
+                raw = self._backend.call(job, args)
             if isinstance(raw, TimedResult):
                 # the job measured its own device compute (SiteJob.timed);
                 # the grid clock is calibrated by real kernels, not by our

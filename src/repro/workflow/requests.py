@@ -74,6 +74,7 @@ class MiningRequest:
     backend: str | None = None
     compute_s: float = 0.0  # this request's share of measured device compute
     fused: bool = False  # served by a cross-request fused dispatch
+    compiles: int = 0  # XLA compiles made while its execution group ran
     error: str | None = None
 
     @property

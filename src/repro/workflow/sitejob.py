@@ -24,6 +24,7 @@ from typing import Any
 
 import jax
 
+from repro.obs import span
 from repro.workflow.dag import DAG, Job, TimedResult
 from repro.workflow.overhead import JobSpec
 
@@ -86,7 +87,9 @@ def timed(fn: Callable[..., Any], record: dict[str, float] | None = None, name: 
     @functools.wraps(fn)
     def wrapper(*args):
         t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args))
+        out = fn(*args)
+        with span("repro.job.ready"):
+            jax.block_until_ready(out)
         dt = time.perf_counter() - t0
         if record is not None:
             record[name or getattr(fn, "__name__", "job")] = dt
@@ -126,7 +129,9 @@ def timed_batch(
 
     def batched(names: list[str], batch_args: list, argss: list) -> list:
         t0 = time.perf_counter()
-        outs = jax.block_until_ready(fused_fn(batch_args, argss))
+        outs = fused_fn(batch_args, argss)
+        with span("repro.job.ready"):
+            jax.block_until_ready(outs)
         share = (time.perf_counter() - t0) / max(len(names), 1)
         if record is not None:
             for name in names:

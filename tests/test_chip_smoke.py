@@ -52,15 +52,18 @@ def test_kernel_phase_demands_mosaic_off_tpu(smoke):
 
 
 def test_service_phase_checks_pass(smoke):
+    from repro.obs import compiles
+
     out = smoke.service_phase(
         n_sites=4, n_tx=2000, n_items=24, n_pts=3000, dim=3, k=3, minsup=0.05,
-        k_local=4, n_components=5, seed=0, clock=smoke.CompileClock(),
+        k_local=4, n_components=5, seed=0, clock=compiles,
     )
     led = out["ledger"]
     assert led["failures"] == 0 and led["fused_fallbacks"] == 0
     assert led["fused_requests"] >= 2
     assert led["device_dispatches"] < led["exec_groups"]
     assert {"service/data", "service/wave1", "service/reference"} <= set(out["times"])
+    assert all(t["compile_s"] >= 0 for t in out["times"].values())
 
 
 def test_check_assign_accepts_ties_only(smoke):
