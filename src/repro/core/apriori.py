@@ -530,6 +530,11 @@ class DeltaApriori:
     def n_tx(self) -> int:
         return sum(db.n_tx for db in self._batches)
 
+    @property
+    def batches(self) -> tuple[TransactionDB, ...]:
+        """The appended batches, packed, in append order."""
+        return tuple(self._batches)
+
     def stream(self) -> TransactionDB:
         """The full appended stream as one DB (lazy concat, cached)."""
         if not self._batches:

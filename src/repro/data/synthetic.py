@@ -76,11 +76,15 @@ def ibm_transactions(
     return dense
 
 
+def split_indices(n: int, n_sites: int, seed: int = 0) -> list[np.ndarray]:
+    """Row indices of each site's shard of ``n`` rows: one seeded
+    permutation cut into ``n_sites`` contiguous pieces (uneven tail ok)."""
+    return np.array_split(np.random.default_rng(seed).permutation(n), n_sites)
+
+
 def split_transactions(dense: np.ndarray, n_sites: int, seed: int = 0) -> list[np.ndarray]:
     """Split a dense transaction DB into per-site shards (uneven tail ok)."""
-    rng = np.random.default_rng(seed)
-    idx = rng.permutation(len(dense))
-    return [dense[s] for s in np.array_split(idx, n_sites)]
+    return [dense[s] for s in split_indices(len(dense), n_sites, seed)]
 
 
 def token_batch(seed: int, batch: int, seq_len: int, vocab: int) -> dict[str, np.ndarray]:

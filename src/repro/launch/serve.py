@@ -38,7 +38,8 @@ arXiv:1412.2673) is a bursty stream of arrivals from many users.
     execution group ran) in the same spirit as the engine's
     ``RunReport``, JSON-serializable for the CI smoke's artifact.
   * **spans** — ``repro.step`` (one tick), ``repro.request`` (one
-    execution group or fused bucket) and ``repro.split`` (the site split)
+    execution group or fused bucket) and ``repro.split`` (the site split:
+    a row gather of the packed table)
     on the profiler's clock (``repro.obs``); see ``docs/serving.md``.
 
 CLI driver (bursty synthetic multi-tenant trace; exits non-zero when any
@@ -85,15 +86,23 @@ APPS = app_names()
 
 @dataclass
 class _Dataset:
-    """Per-dataset incremental state the service maintains across appends."""
+    """Per-dataset incremental state the service maintains across appends.
+
+    A transactions dataset is held packed, never dense: the delta-Apriori
+    state keeps every appended batch as a packed ``TransactionDB`` on the
+    device, and :meth:`packed` gives the whole table as one packed host
+    array, copied from the device once per version.  A grid split
+    gathers each site's rows from that table; no dense copy is kept."""
 
     name: str
     kind: str  # "transactions" | "points"
     version: int = 0
-    # transactions: the appended dense batches plus the delta-Apriori state
+    # transactions: the delta-Apriori state (the packed batches) plus the
+    # packed host table of the version it was built for
     n_items: int | None = None
     delta: DeltaApriori | None = None
-    tx_batches: list = field(default_factory=list)
+    _packed: np.ndarray | None = field(default=None, init=False, repr=False)
+    _packed_version: int = field(default=-1, init=False, repr=False)
     # points: appended (n, dim) batches plus per-k warm-start centroids
     dim: int | None = None
     pt_batches: list = field(default_factory=list)
@@ -102,8 +111,19 @@ class _Dataset:
     def pooled_points(self) -> np.ndarray:
         return np.concatenate(self.pt_batches, axis=0)
 
-    def pooled_dense(self) -> np.ndarray:
-        return np.concatenate(self.tx_batches, axis=0)
+    def packed(self) -> np.ndarray:
+        """The whole transactions table, packed: (n_tx, W) uint32 on the
+        host, rows in append order.  Rebuilt on the first call after an
+        append; the host concatenation keeps every compile off the
+        dataset's length."""
+        if self._packed_version != self.version:
+            parts = [np.asarray(db.packed) for db in self.delta.batches]
+            table = parts[0] if len(parts) == 1 else np.concatenate(parts)
+            # rows contiguous for the split's gathers: a TPU's host copy of
+            # a device array can be column-major
+            self._packed = np.ascontiguousarray(table)
+            self._packed_version = self.version
+        return self._packed
 
 
 class MiningService:
@@ -217,7 +237,6 @@ class MiningService:
             raise ValueError(f"dataset {name!r} holds points, not transactions")
         dense = np.asarray(dense_batch, dtype=bool)
         ds.delta.append(dense)
-        ds.tx_batches.append(dense)
         ds.version = ds.delta.version
         return ds.version
 

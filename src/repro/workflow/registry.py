@@ -355,14 +355,21 @@ def _frequent_digest(frequent: dict) -> dict:
 
 
 def _tx_sites(ds, p, svc) -> list:
-    """Service-side split of a transactions dataset into per-site DBs."""
+    """Service-side split of a transactions dataset into per-site DBs: each
+    site's rows gathered from the dataset's packed table and uploaded.
+    Packing works row by row, so a site equals ``TransactionDB.from_dense``
+    of the same rows of ``split_transactions``, bit for bit."""
+    import jax.numpy as jnp
+
     from repro.core.apriori import TransactionDB
-    from repro.data.synthetic import split_transactions
+    from repro.data.synthetic import split_indices
 
     n = p["n_sites"] if p["n_sites"] is not None else svc.n_sites
+    packed = ds.packed()
     return [
-        TransactionDB.from_dense(s)
-        for s in split_transactions(ds.pooled_dense(), int(n), seed=p["split_seed"])
+        TransactionDB(packed=jnp.asarray(np.take(packed, idx, axis=0)), n_items=ds.n_items,
+                      n_tx=len(idx))
+        for idx in split_indices(len(packed), int(n), seed=p["split_seed"])
     ]
 
 
