@@ -1,19 +1,18 @@
 """The comparison that decides ``correct``.
 
 Once the window has closed, every answer that the window's requests got
-is compared with the plain reference (``bench.reference``) over the
-dataset.
+is compared with the plain reference of the dataset's kind
+(``bench/kinds/<kind>.py``) over the dataset.
 
-Numbers compared, each with its limit (``LIMITS``; see PERF.md for the
-readings they were set from): ``failed_requests``, ``itemset_gap``
-(itemsets frequent in one of the answer and the reference only) and
-``count_error`` (largest gap of a support count).  Exact: limit 0.
+Numbers compared, each with its limit: ``failed_requests`` (limit 0),
+``compared_answers`` when no answer came back at all (always fails), and
+the kind's own (its ``LIMITS``; see PERF.md for the readings they were
+set from).
 
-``control`` replaces the answers with a broken stand-in, for the runs
-and tests that must see ``correct`` false: ``"bf16"`` is the reference
-itself computed in bfloat16 (support counts held in bfloat16), which
-breaks the exactness the configuration states.  ``planted`` breaks the
-timed path underneath, for the tests of each fault a cell can have.
+``control`` replaces the answers with a broken stand-in that the kind
+computes (one of its ``CONTROLS``), for the runs and tests that must see
+``correct`` false.  ``planted`` breaks the timed path underneath with one
+of the kind's ``FAULTS``, for the tests of each fault a cell can have.
 """
 
 from __future__ import annotations
@@ -23,13 +22,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from bench import reference
-
-LIMITS = {
-    "failed_requests": 0,
-    "itemset_gap": 0,
-    "count_error": 0,
-}
+FAILED_LIMIT = 0
 
 
 @dataclass
@@ -50,47 +43,26 @@ class Answer:
     value: dict  # the answer, on the host
 
 
-def _host_answer(app: str, res) -> dict:
-    if app in ("gfm", "fdm", "cd_apriori"):
-        return {"frequent": {tuple(int(i) for i in k): int(v) for k, v in res.frequent.items()}}
-    raise ValueError(f"no comparison for app {app!r}")
-
-
 def answers(dep, records) -> list[Answer]:
-    """Every answer of the window's completed requests, copied to the host."""
+    """Every answer of the window's completed requests, copied to the host
+    by the deployment's kind."""
     svc, out = dep.service, []
     for rid, rec, req in records:
         if rid is None or not rec.ok:
             continue
         out.append(Answer(app=req.app, params=dict(svc.request(rid).params),
-                          value=_host_answer(req.app, svc.result(rid))))
+                          value=dep.kind.host_answer(req.app, svc.result(rid))))
     return out
 
 
-def _itemset_checks(rows: np.ndarray, ans: list[Answer], control: str | None) -> list[Check]:
-    worst = {"itemset_gap": 0, "count_error": 0}
-    ref = reference.ItemsetReference(rows)
-    stand_in = reference.ItemsetReference(rows, "bfloat16") if control == "bf16" else None
-    n = rows.shape[0]
-    k_max = max(a.params["k"] for a in ans)
-    ref.frequent(min(reference.min_count(a.params["minsup"], n) for a in ans), k_max)
-    for a in ans:
-        thr = reference.min_count(a.params["minsup"], n)
-        want = ref.frequent(thr, a.params["k"])
-        got = stand_in.frequent(thr, a.params["k"]) if stand_in is not None else a.value["frequent"]
-        worst["itemset_gap"] = max(worst["itemset_gap"], len(want.keys() ^ got.keys()))
-        gaps = [abs(got[i] - want[i]) for i in want.keys() & got.keys()]
-        worst["count_error"] = max([worst["count_error"], *gaps])
-    return [Check(name, v, LIMITS[name]) for name, v in worst.items()]
-
-
-def compare(rows: np.ndarray, ans: list[Answer], *, failed: int = 0,
+def compare(kind, rows: np.ndarray, ans: list[Answer], *, failed: int = 0,
             control: str | None = None) -> list[Check]:
-    """Every number compared, with its limit; ``rows`` is the dataset."""
-    out = [Check("failed_requests", failed, LIMITS["failed_requests"])]
+    """Every number compared, with its limit; ``rows`` is the dataset and
+    ``kind`` its kind's module."""
+    out = [Check("failed_requests", failed, FAILED_LIMIT)]
     if not ans:
         return out + [Check("compared_answers", 0, -1)]  # nothing came back
-    return out + _itemset_checks(rows, ans, control)
+    return out + kind.compare(rows, ans, control)
 
 
 # ---------------------------------------------------------------------------
@@ -98,52 +70,24 @@ def compare(rows: np.ndarray, ans: list[Answer], *, failed: int = 0,
 # ---------------------------------------------------------------------------
 
 
-def _patch(obj, name: str, make):
+def patch(obj, name: str, make):
+    """Replace ``obj.name`` by ``make(original)``; returns the undo."""
     orig = getattr(obj, name)
     setattr(obj, name, make(orig))
     return lambda: setattr(obj, name, orig)
 
 
 @contextlib.contextmanager
-def planted(fault: str | None):
-    """Break the timed path underneath the service while the block runs.
-
-    * ``answer_altered``: the support-count kernel adds one to a count;
-    * ``half_batch``: support counts come from the first half of the
-      transactions, doubled.
-    """
-    undo = []
-    if fault is None or fault == "bf16":
+def planted(fault: str | None, kind):
+    """Break the timed path underneath the service with the kind's fault
+    ``fault`` while the block runs; a control, or ``None``, plants
+    nothing."""
+    if fault is None or fault in kind.CONTROLS:
         yield
         return
-    from repro.kernels import ops
-
-    if fault == "answer_altered":
-        def count_plus_one(orig):
-            def f(*a, **kw):
-                out = orig(*a, **kw)
-                if isinstance(out, tuple):
-                    return (out[0].at[..., 0].add(1),) + tuple(out[1:])
-                return out.at[..., 0].add(1)
-            return f
-        for e in ("support_count", "support_count_prune", "support_count_sites",
-                  "support_count_prune_sites"):
-            undo.append(_patch(ops, e, count_plus_one))
-
-    elif fault == "half_batch":
-        def half_rows(orig):
-            def f(tx, *a, **kw):
-                n = tx.shape[-2]
-                kept = tx.at[..., n // 2:, :].set(0)
-                out = orig(kept, *a, **kw)
-                return (out[0] * 2,) + tuple(out[1:]) if isinstance(out, tuple) else out * 2
-            return f
-        for e in ("support_count", "support_count_prune", "support_count_sites",
-                  "support_count_prune_sites"):
-            undo.append(_patch(ops, e, half_rows))
-
-    else:
-        raise ValueError(f"unknown fault {fault!r}")
+    if fault not in kind.FAULTS:
+        raise ValueError(f"unknown fault {fault!r} (have {sorted(kind.FAULTS)})")
+    undo = kind.FAULTS[fault]()
     try:
         yield
     finally:

@@ -1,20 +1,23 @@
 """A configuration's deployment: its data, made from the run's seed, and
 the service built with the configuration's constructor arguments.
 
-A configuration file names its dataset's ``kind`` (``transactions``),
-the generator and its parameters under ``data`` and the
-``MiningService`` arguments under ``service``.  The data generators are
-the benchmark's own (``bench/gen``), so the yardstick does not move when
-the program's generators change.
+A configuration file names its dataset's ``kind``, the generator and its
+parameters under ``data`` and the ``MiningService`` arguments under
+``service``.  Both are found by name: the generator at
+``bench/gen/<generator>.py``, the kind at ``bench/kinds/<kind>.py``
+(``bench.spec``).  The data generators are the benchmark's own, so the
+yardstick does not move when the program's generators change.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
+from pathlib import Path
 
 import numpy as np
 
-from bench.gen.quest import quest_patterns, quest_transactions
+from bench import spec
+from bench.spec import ROOT
 
 
 @dataclass
@@ -23,30 +26,22 @@ class Deployment:
     service: object  # repro.launch.serve.MiningService
     dataset: str
     rows: np.ndarray  # the dataset as loaded
+    kind: object  # the dataset kind's module (bench/kinds/<kind>.py)
 
 
-def make_rows(config: dict, seed) -> np.ndarray:
+def make_rows(config: dict, seed, root: Path = ROOT) -> np.ndarray:
     """The configuration's rows from ``seed`` (an int or a sequence of ints)."""
-    d = config["data"]
-    if d["generator"] == "quest":
-        pats = quest_patterns(d["pattern_seed"], d["n_items"], d["n_patterns"],
-                              d["avg_pattern_len"], correlation=d["correlation"],
-                              corruption_mean=d["corruption_mean"],
-                              corruption_var=d["corruption_var"])
-        return quest_transactions(seed, d["n_tx"], d["n_items"], pats, avg_tx_len=d["avg_tx_len"])
-    raise ValueError(f"unknown generator {d['generator']!r}")
+    return spec.generator(config["data"]["generator"], root).rows(config["data"], seed)
 
 
-def build(config: dict, seed: int) -> Deployment:
+def build(config: dict, seed: int, root: Path = ROOT) -> Deployment:
     """Generate the data from ``seed``, build the service, register and
     load the dataset."""
     from repro.launch.serve import MiningService
 
-    rows = make_rows(config, seed)
+    kind = spec.kind(config["kind"], root)
+    rows = make_rows(config, seed, root)
     svc = MiningService(**config["service"])
-    name, kind, d = config["dataset"], config["kind"], config["data"]
-    if kind != "transactions":
-        raise ValueError(f"no deployment of {kind!r} datasets")
-    svc.register_dataset(name, kind, n_items=d["n_items"])
-    svc.append_transactions(name, rows)
-    return Deployment(config=config, service=svc, dataset=name, rows=rows)
+    kind.load(svc, config["dataset"], config["data"], rows)
+    return Deployment(config=config, service=svc, dataset=config["dataset"], rows=rows,
+                      kind=kind)

@@ -24,6 +24,10 @@ Two differences of set-up, both deliberate and neither of distribution:
 * the pattern table comes from its own seed (the deployment's, fixed in
   the configuration), so every run seed mines the same structure and
   only the transactions differ; rows are drawn in blocks with NumPy.
+
+``rows(data, seed)`` is the generator as the harness finds it by a
+configuration's ``data.generator``; ``TINY`` is the size a CPU test runs
+it at.
 """
 
 from __future__ import annotations
@@ -98,3 +102,19 @@ def quest_transactions(seed, n_tx: int, n_items: int, patterns: Patterns, *,
             got[live] += np.where(put, new, 0)
             live = live[put & (got[live] < target[live])]
     return dense
+
+
+# a size a test run holds: fewer rows and (for the interpreted kernels'
+# sake) fewer items and patterns
+TINY = {"n_tx": 2400, "n_items": 96, "n_patterns": 24}
+
+
+def rows(data: dict, seed) -> np.ndarray:
+    """The configuration's transactions (``data`` is its ``data`` group)
+    from ``seed``, an int or a sequence of ints."""
+    pats = quest_patterns(data["pattern_seed"], data["n_items"], data["n_patterns"],
+                          data["avg_pattern_len"], correlation=data["correlation"],
+                          corruption_mean=data["corruption_mean"],
+                          corruption_var=data["corruption_var"])
+    return quest_transactions(seed, data["n_tx"], data["n_items"], pats,
+                              avg_tx_len=data["avg_tx_len"])

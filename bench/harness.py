@@ -61,7 +61,7 @@ class Ctx:
 
     cell: spec.Cell
     requests: list = field(default_factory=list)  # per-request dicts, window only
-    kernel_calls: list = field(default_factory=list)  # bench.kernels.Call
+    kernel_calls: list = field(default_factory=list)  # the kind's kernel call records
     trace: object = None  # bench.trace.Summary
     peaks: dict | None = None
 
@@ -172,6 +172,8 @@ def _request_rows(svc, records) -> list[dict]:
     rows = []
     for rid, rec, req in records:
         row = {"app": req.app, "params": req.params, "latency_s": rec.latency_s, "ok": rec.ok}
+        if rid is not None:
+            row["compiles"] = int(svc.request(rid).compiles)
         if rec.ok:
             comm = getattr(svc.result(rid), "comm", None)
             if comm is not None:
@@ -241,7 +243,8 @@ def run_cell(
     ``control`` puts a broken variant in the timed path's place (see
     ``bench.checks``), for the runs and tests that must see ``correct``
     false; ``warm=False`` skips the warm-up, for runs that read only the
-    comparison."""
+    comparison.  ``root`` is the checkout whose benchmark files (cell,
+    configuration, mix, generator, kind, readers) describe the run."""
     cell = spec.load_cell(name, root)
     if config is not None:
         cell = spec.Cell(**{**cell.__dict__, "config": config})
@@ -265,8 +268,9 @@ def run_cell(
 
     plan = traffic.rounds(mix, seed)
     with span("bench.setup.data"):
-        dep = deploy.build(cell.config, seed)
-    with checks.planted(control):
+        dep = deploy.build(cell.config, seed, root)
+    kind = dep.kind
+    with checks.planted(control, kind):
         with span("bench.setup.warmup"):
             n_warm = _warm_up(dep, mix, plan) if warm else 0
         setup_s = time.perf_counter() - t_start
@@ -280,7 +284,7 @@ def run_cell(
             from bench.trace import start
 
             shutil.rmtree(trace_dir, ignore_errors=True)
-            recorder = KernelRecorder().__enter__()
+            recorder = KernelRecorder(kind.KERNELS).__enter__()
             start(trace_dir)
         with span("bench.window"):
             records, t_open = _window(dep, mix, plan, seconds)
@@ -318,11 +322,11 @@ def run_cell(
     data = dep.rows
     del svc, dep, records
     gc.collect()
-    compared = checks.compare(data, answers, failed=result["failed"])
-    if control == "bf16":  # the program's readings first, then the control's
+    compared = checks.compare(kind, data, answers, failed=result["failed"])
+    if control in kind.CONTROLS:  # the program's readings first, then the control's
         result["program_checks"] = {c.name: {"value": c.value, "limit": c.limit}
                                     for c in compared}
-        compared = checks.compare(data, answers, failed=result["failed"],
+        compared = checks.compare(kind, data, answers, failed=result["failed"],
                                   control=control)
     for c in compared:
         log(f"check {c.name}: {c.value!r} (limit {c.limit!r})")

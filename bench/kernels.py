@@ -1,34 +1,19 @@
-"""Records the shapes of the support-count kernel's calls at their public
-entry points (``repro.kernels.ops``), for the roofline reader.
+"""Records the kernel calls of the traced window at the program's public
+entry points (``repro.kernels.ops``), for the roofline readers.
 
+Which entry points, and what one call's record holds, is the dataset
+kind's (its ``KERNELS``: entry point -> ``take(entry, *args, **kw)``).
 While the window is open the recorder swaps each entry point for a
 wrapper, and puts the original back when it closes.  Each call made with
-concrete arrays runs once, so it is one ``Call``; its candidate masks are
-kept by reference, and their non-empty rows are counted once the window
-has closed (``resolve``), so the window makes no copy to the host.  A
-call made while JAX traces a jitted function is not recorded: how often
-it ran cannot be told from the call.  Shapes are the problem's:
-candidate rows that are all zero (padding) are not counted, and the
-lane padding that the entry points add themselves never reaches the
-wrapper.
+concrete arrays runs once, so it is one record.  A record may keep
+arrays by reference; ``resolve`` runs each record's ``resolve()`` once
+the window has closed, so the window makes no copy to the host.  A call
+made while JAX traces a jitted function is not recorded: how often it
+ran cannot be told from the call.  An entry point called from inside
+another recorded one is not recorded again.
 """
 
 from __future__ import annotations
-
-from dataclasses import dataclass
-
-ENTRIES = {"support_count": False, "support_count_prune": False,
-           "support_count_sites": True, "support_count_prune_sites": True}
-
-
-@dataclass
-class Call:
-    entry: str  # the ops function called
-    sites: int
-    n_tx: int  # rows a site
-    words: int  # 32-bit words a row
-    masks: object  # the candidate masks, until resolved
-    n_cand_total: int | None = None  # non-empty candidates over all sites
 
 
 def _is_tracer(x) -> bool:
@@ -39,23 +24,23 @@ def _is_tracer(x) -> bool:
 
 class KernelRecorder:
     """Context manager: while open, every concrete call of an entry point
-    appends a ``Call`` to ``calls``."""
+    of ``entries`` appends its record to ``calls``."""
 
-    def __init__(self):
-        self.calls: list[Call] = []
+    def __init__(self, entries: dict):
+        self.entries = entries
+        self.calls: list = []
         self._saved: dict = {}
-        self._depth = 0  # the *_sites forms call the single forms inside
+        self._depth = 0  # entry points that call others inside
 
-    def _wrap(self, ops, entry: str, sites: bool):
+    def _wrap(self, ops, entry: str, take):
         orig = getattr(ops, entry)
 
-        def wrapper(tx, masks, *args, **kw):
-            if self._depth == 0 and not (_is_tracer(tx) or _is_tracer(masks)):
-                s, n, w = tx.shape if sites else (1, *tx.shape)
-                self.calls.append(Call(entry=entry, sites=s, n_tx=n, words=w, masks=masks))
+        def wrapper(*args, **kw):
+            if self._depth == 0 and not any(map(_is_tracer, (*args, *kw.values()))):
+                self.calls.append(take(entry, *args, **kw))
             self._depth += 1
             try:
-                return orig(tx, masks, *args, **kw)
+                return orig(*args, **kw)
             finally:
                 self._depth -= 1
 
@@ -65,8 +50,8 @@ class KernelRecorder:
     def __enter__(self):
         from repro.kernels import ops
 
-        for entry, sites in ENTRIES.items():
-            self._wrap(ops, entry, sites)
+        for entry, take in self.entries.items():
+            self._wrap(ops, entry, take)
         return self
 
     def __exit__(self, *exc):
@@ -77,14 +62,8 @@ class KernelRecorder:
         self._saved.clear()
         return False
 
-    def resolve(self) -> list[Call]:
-        """The calls, each with its non-empty candidates counted from its
-        masks (all-zero rows are padding); drops the masks."""
-        import numpy as np
-
+    def resolve(self) -> list:
+        """The records, each resolved."""
         for c in self.calls:
-            if c.masks is not None:
-                m = np.asarray(c.masks).reshape(-1, c.words)
-                c.n_cand_total = int((m != 0).any(axis=1).sum())
-                c.masks = None
+            c.resolve()
         return self.calls

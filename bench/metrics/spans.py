@@ -1,14 +1,13 @@
 """The program's own spans in a traced run: for each span name that starts
-``repro.``, its count and self seconds inside the traced window; the
-compiles recorded on each request span; and the device's idle gaps named
-by the innermost span open at their midpoints.
+``repro.``, its count and self seconds inside the traced window, and the
+compiles recorded on each request span.  (The device's idle gaps, named
+by the innermost of these spans open at their midpoints, are
+``bench.trace``'s.)
 
 The program opens these spans itself (``repro.obs.span``) on the host
 plane of the same profile as the device's operations, on the same clock.
 Self seconds are a span's time inside the window less the part covered
-by the program spans nested in it on the same host line.  A gap is named
-by the innermost span of the program (``repro.``) or of the benchmark
-(``bench.``, other than ``bench.window``) open at its midpoint.
+by the program spans nested in it on the same host line.
 
 The span metrics' readers share one reduction of the profile that the
 harness writes for the window, under ``.bench_trace/<cell>`` at the root
@@ -21,10 +20,9 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from pathlib import Path
 
-from bench.trace import DEVICE_PLANE, OPS_LINE, _xplane, union_length
+from bench.trace import _xplane
 
 PREFIX = "repro."
-BENCH_PREFIX = "bench."
 WINDOW = "bench.window"
 REQUEST = "repro.request"
 
@@ -35,7 +33,6 @@ class Spans:
     spans: dict = field(default_factory=dict)  # name -> [count, self seconds]
     # (compiles, requests) of each request span that starts in the window
     request_compiles: list = field(default_factory=list)
-    gaps: list = field(default_factory=list)  # (seconds, span name), longest first
 
     def share(self, name: str) -> float:
         """``name``'s self time as a % of the window; 0 if it never ran."""
@@ -74,15 +71,10 @@ def reduce(path: Path) -> Spans | None:
 
     pd = ProfileData.from_file(str(_xplane(Path(path))))
     lines: list[list] = []  # per host line, its program spans
-    named: list = []  # every span a gap can be named by
-    busy: list = []  # device op intervals
     requests: list = []  # (start, compiles, number of request ids)
     t_lo = t_hi = None
     for plane in pd.planes:
-        if DEVICE_PLANE.match(plane.name):
-            busy += [(ev.start_ns, ev.start_ns + ev.duration_ns)
-                     for line in plane.lines if line.name == OPS_LINE for ev in line.events]
-        elif plane.name.startswith("/host"):
+        if plane.name.startswith("/host"):
             for line in plane.lines:
                 mine = []
                 for ev in line.events:
@@ -91,13 +83,10 @@ def reduce(path: Path) -> Spans | None:
                         t_lo, t_hi = s, e
                     elif ev.name.startswith(PREFIX):
                         mine.append((s, e, ev.name))
-                        named.append((s, e, ev.name))
                         if ev.name == REQUEST:
                             meta = dict(ev.stats)
                             ids = str(meta.get("request_ids", "")).split()
                             requests.append((s, int(meta.get("compiles", 0)), len(ids)))
-                    elif ev.name.startswith(BENCH_PREFIX):
-                        named.append((s, e, ev.name))
                 if mine:
                     lines.append(mine)
     if not lines:
@@ -110,21 +99,8 @@ def reduce(path: Path) -> Spans | None:
         for name, (n, secs) in self_times(line, t_lo, t_hi).items():
             spans[name][0] += n
             spans[name][1] += secs
-    _, merged = union_length([(max(s, t_lo), min(e, t_hi)) for s, e in busy
-                              if e > t_lo and s < t_hi])
-    edges = [t_lo] + [t for iv in merged for t in iv] + [t_hi]
-    gaps = []
-    for a, b in zip(edges[0::2], edges[1::2]):
-        if b > a:
-            mid = (a + b) / 2
-            inner = [sp for sp in named if sp[0] <= mid <= sp[1]]
-            # the innermost: the latest to open, and of those the first to close
-            name = max(inner, key=lambda sp: (sp[0], -sp[1]))[2] if inner else "no span"
-            gaps.append(((b - a) / 1e9, name))
-    gaps.sort(key=lambda g: -g[0])
     return Spans(window_s=(t_hi - t_lo) / 1e9, spans=dict(spans),
-                 request_compiles=[(c, n) for s, c, n in requests if t_lo <= s < t_hi],
-                 gaps=gaps)
+                 request_compiles=[(c, n) for s, c, n in requests if t_lo <= s < t_hi])
 
 
 _cache: dict = {}

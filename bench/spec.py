@@ -1,16 +1,20 @@
 """What a run is asked to do, found by name: the cell in ``BENCHMARK.json``,
 its configuration under ``bench/configs/``, its traffic mix under
-``bench/traffic/``, its per-layer metric readers under ``bench/metrics/``,
-and the chip's peaks in ``bench/peaks.json``.
+``bench/traffic/``, the data generator the configuration names under
+``bench/gen/``, its dataset kind under ``bench/kinds/``, its per-layer
+metric readers under ``bench/metrics/``, and the chip's peaks in
+``bench/peaks.json``.
 
-Nothing here knows a cell, a configuration, a mix or a metric by name:
-a later change adds one with new files and ``BENCHMARK.json`` entries.
+Nothing here knows a cell, a configuration, a mix, a generator, a kind
+or a metric by name: a later change adds one with new files and
+``BENCHMARK.json`` entries.
 """
 
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
 
@@ -65,15 +69,35 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     )
 
 
+def _module(path: Path, what: str, mod_name: str):
+    """The Python file at ``path``, loaded as a module; ``what`` names
+    it in the error when the file is missing."""
+    if not path.is_file():
+        raise SpecError(f"{what} has no file at {path}")
+    mod_spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[mod_name] = mod  # a dataclass looks its module up there
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str, root: Path = ROOT):
     """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise SpecError(f"per-layer metric {name!r} has no reader at {path}")
-    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _module(root / "bench" / "metrics" / f"{name}.py", f"per-layer metric {name!r}",
+                   f"bench_metric_{name}").read
+
+
+def generator(name: str, root: Path = ROOT):
+    """The data generator ``bench/gen/<name>.py``: ``rows(data, seed)``
+    and ``TINY``, the sizes a CPU test runs it at."""
+    return _module(root / "bench" / "gen" / f"{name}.py", f"data generator {name!r}",
+                   f"bench_gen_{name}")
+
+
+def kind(name: str, root: Path = ROOT):
+    """The dataset kind ``bench/kinds/<name>.py`` (see ``bench/kinds``)."""
+    return _module(root / "bench" / "kinds" / f"{name}.py", f"dataset kind {name!r}",
+                   f"bench_kind_{name}")
 
 
 def peaks(device_kind: str, root: Path = ROOT) -> dict:

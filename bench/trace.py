@@ -1,18 +1,19 @@
 """Reduce a profiler trace (``.xplane.pb``) to the device numbers the
 benchmark reports: busy time (the union of the intervals in which an
-operation ran on a chip, clipped to the window), the traced window, each device operation's
-time, and the longest idle gaps named by the benchmark's host span open
-at the time.
+operation ran on a chip, clipped to the window), the traced window, each
+device operation's time, and the longest idle gaps, each named by the
+innermost host span open at its midpoint.
 
 Device planes are those named ``/device:TPU:<n>``; their operations are
 the events of the line ``XLA Ops``, named by HLO instruction.  Host spans
-are the benchmark's own ``TraceAnnotation`` events (names starting
-``bench.``) on the host plane.
+are the ``TraceAnnotation`` events on the host plane of the benchmark
+(names starting ``bench.``) and of the program (``repro.``).
 Times within one trace share a clock, in nanoseconds.
 """
 
 from __future__ import annotations
 
+import heapq
 import re
 from collections import defaultdict
 from dataclasses import dataclass, field
@@ -21,6 +22,8 @@ from pathlib import Path
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "repro."
+WINDOW = "bench.window"
 
 
 def op_name(event_name: str) -> str:
@@ -50,6 +53,30 @@ def union_length(intervals: list[tuple[float, float]]) -> tuple[float, list[tupl
         else:
             merged.append([s, e])
     return sum(e - s for s, e in merged), [(s, e) for s, e in merged]
+
+
+def idle_gaps(merged: list[tuple[float, float]], t_lo: float, t_hi: float, spans: list,
+              nothing: str) -> list[tuple[float, str]]:
+    """(seconds, name) of each gap between the merged busy intervals
+    inside [t_lo, t_hi], longest first.  A gap is named by the innermost
+    of ``spans`` ((start, end, name) tuples) open at its midpoint: the
+    latest to open, and of those the first to close; ``nothing`` where
+    none is open."""
+    edges = [t_lo] + [t for iv in merged for t in iv] + [t_hi]
+    mids = sorted(((a + b) / 2, a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a)
+    by_start = sorted(spans)
+    open_: list = []  # heap of (-start, end, name); ended spans leave lazily
+    gaps, i = [], 0
+    for mid, a, b in mids:
+        while i < len(by_start) and by_start[i][0] <= mid:
+            s, e, name = by_start[i]
+            heapq.heappush(open_, (-s, e, name))
+            i += 1
+        while open_ and open_[0][1] < mid:  # midpoints rise: an ended span stays ended
+            heapq.heappop(open_)
+        gaps.append(((b - a) / 1e9, open_[0][2] if open_ else nothing))
+    gaps.sort(key=lambda g: -g[0])
+    return gaps
 
 
 @dataclass
@@ -94,7 +121,8 @@ def reduce(path: Path) -> Summary:
 
     pd = ProfileData.from_file(str(_xplane(Path(path))))
     chips: list[list] = []  # per chip, its ops' (start, end, name)
-    spans: list = []
+    spans: list = []  # the benchmark's
+    program: list = []  # the program's
     t_lo, t_hi = None, None
     for plane in pd.planes:
         if DEVICE_PLANE.match(plane.name):
@@ -105,10 +133,13 @@ def reduce(path: Path) -> Summary:
         elif plane.name.startswith("/host"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith(SPAN_PREFIX):
-                        spans.append((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name))
-                        if ev.name == "bench.window":
-                            t_lo, t_hi = ev.start_ns, ev.start_ns + ev.duration_ns
+                    s, e = ev.start_ns, ev.start_ns + ev.duration_ns
+                    if ev.name == WINDOW:
+                        t_lo, t_hi = s, e
+                    elif ev.name.startswith(SPAN_PREFIX):
+                        spans.append((s, e, ev.name))
+                    elif ev.name.startswith(PROGRAM_PREFIX):
+                        program.append((s, e, ev.name))
     if t_lo is None:  # no window span: the extent of everything recorded
         edges = [t for chip in chips for s, e, _ in chip for t in (s, e)]
         edges += [t for s, e, _ in spans for t in (s, e)]
@@ -130,13 +161,5 @@ def reduce(path: Path) -> Summary:
     busy_s = sum(busy_by_chip) / len(busy_by_chip) if busy_by_chip else 0.0
     # idle gaps: when no chip ran anything, inside the window
     _, merged = union_length(intervals)
-    edges = [t_lo] + [t for iv in merged for t in iv] + [t_hi]
-    gaps = []
-    for a, b in zip(edges[0::2], edges[1::2]):
-        if b > a:
-            mid = (a + b) / 2
-            inner = [sp for sp in spans if sp[0] <= mid <= sp[1] and sp[2] != "bench.window"]
-            name = max(inner, key=lambda sp: sp[0])[2] if inner else "no bench span"
-            gaps.append(((b - a) / 1e9, name))
-    gaps.sort(key=lambda g: -g[0])
+    gaps = idle_gaps(merged, t_lo, t_hi, spans + program, "no bench span")
     return Summary(window_s=window_s, busy_s=busy_s, ops=dict(ops), gaps=gaps)

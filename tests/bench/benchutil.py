@@ -13,18 +13,15 @@ for p in (str(ROOT), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-# a size a test run holds: fewer rows, sites and (for the interpreted
-# kernels' sake) items and patterns
-TINY_DATA = {"quest": {"n_tx": 2400, "n_items": 96, "n_patterns": 24}}
-
 
 def tiny_cell(name: str, *, root: Path = ROOT):
-    """(config, mix) of cell ``name`` cut to a size a test run holds."""
+    """(config, mix) of cell ``name`` cut to a size a test run holds: its
+    generator's ``TINY`` sizes, and four sites."""
     from bench import spec
 
     cell = spec.load_cell(name, root)
     cfg = copy.deepcopy(cell.config)
-    cfg["data"].update(TINY_DATA[cfg["data"]["generator"]])
+    cfg["data"].update(spec.generator(cfg["data"]["generator"], root).TINY)
     cfg["service"]["n_sites"] = 4
     return cfg, copy.deepcopy(cell.traffic)
 

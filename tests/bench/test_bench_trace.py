@@ -104,3 +104,44 @@ def test_a_trace_recorded_on_one_v5e():
     gaps = s.breakdown()["idle_gaps"]
     assert gaps[0][0] == "bench.tiny" and 0.010 < gaps[0][1] < 0.013  # the sleeps
     assert gaps[1][0] == "bench.tiny" and 0.010 < gaps[1][1] < 0.013
+
+
+def test_program_span_names_the_gaps_of_the_v5e_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    # the recorded trace's device ops and its bench.tiny span, rewritten
+    # with a program span open inside bench.tiny from 1 ns after it opens
+    # to 1 ns before it closes
+    pd = ProfileData.from_file(str(Path(__file__).with_name("tiny_v5e.xplane.pb")))
+    ops, host = [], []
+    for plane in pd.planes:
+        for line in plane.lines:
+            if plane.name == "/device:TPU:0" and line.name == trace.OPS_LINE:
+                ops = [(ev.start_ns, ev.duration_ns, trace.op_name(ev.name))
+                       for ev in line.events]
+            host += [(ev.start_ns, ev.duration_ns, ev.name) for ev in line.events
+                     if ev.name == "bench.tiny"]
+    (t0, dur, _), = host
+    host.append((t0 + 1, dur - 2, "repro.level.count"))
+
+    def plane(pid, name, line, events):
+        names = sorted({n for _, _, n in events})
+        ids = {n: i + 1 for i, n in enumerate(names)}
+        evs = "\n".join(f"events {{ metadata_id: {ids[n]} offset_ps: {round(s * 1000)} "
+                        f"duration_ps: {round(d * 1000)} }}" for s, d, n in events)
+        meta = "\n".join(f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}" }} }}'
+                         for n, i in ids.items())
+        return (f'planes {{ id: {pid} name: "{name}" lines {{ id: {pid} name: "{line}" '
+                f"timestamp_ns: 0 {evs} }} {meta} }}")
+
+    path = tmp_path / "v5e_program.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(
+        plane(1, "/device:TPU:0", trace.OPS_LINE, ops) + plane(2, "/host:CPU", "python", host)))
+    s, base = trace.reduce(path), trace.reduce(Path(__file__).with_name("tiny_v5e.xplane.pb"))
+    assert s.window_s == pytest.approx(base.window_s) and s.busy_s == pytest.approx(base.busy_s)
+    assert s.kernel(r"support_count") == (2, pytest.approx(3.068e-05))
+    gaps = s.breakdown()["idle_gaps"]
+    assert {name for _, name in s.gaps} == {"repro.level.count"}
+    assert 0.010 < gaps[0][1] < 0.013 and 0.010 < gaps[1][1] < 0.013  # the sleeps
+    assert [round(secs, 9) for _, secs in gaps] == [round(secs, 9) for _, secs in
+                                                    base.breakdown()["idle_gaps"]]

@@ -213,7 +213,7 @@ def test_the_recorder_counts_each_call_s_non_empty_candidates():
     masks = np.zeros((2, 4, 1), np.uint32)
     masks[0, :2, 0] = [1, 3]
     masks[1, 0, 0] = 2
-    with KernelRecorder() as rec:
+    with KernelRecorder(spec.kind("transactions").KERNELS) as rec:
         ops.support_count_sites(tx, jnp.asarray(masks))
     assert ops.support_count_sites is before
     (call,) = rec.resolve()
@@ -224,8 +224,7 @@ def test_the_recorder_counts_each_call_s_non_empty_candidates():
 def test_support_count_roofline_reads_calls_over_kernel_time():
     from types import SimpleNamespace
 
-    from bench.kernels import Call
-
+    Call = spec.kind("transactions").Call
     read = spec.metric_reader("support_count_roofline")
     cell = spec.load_cell("itemsets.oneshot")
     calls = [Call("support_count_prune_sites", sites=200, n_tx=2500, words=32, masks=None,
