@@ -39,6 +39,7 @@ from repro.core.stats import (
     merge_cost,
     stack_site_stats,
 )
+from repro.obs import span
 
 
 class VClusterConfig(NamedTuple):
@@ -63,9 +64,26 @@ class MergeResult(NamedTuple):
     n_global: jax.Array  # () int32 — number of live global clusters
 
 
+class PerturbCounts(NamedTuple):
+    """What one site's border-perturbation scan did (int32 scalars)."""
+
+    steps: jax.Array  # candidate slots the scan stepped through, live or not
+    live: jax.Array  # steps that carried a border candidate
+    moved: jax.Array  # points moved to another global cluster
+
+
 # ---------------------------------------------------------------------------
 # Phase 2/3: logical merge over gathered sufficient statistics
 # ---------------------------------------------------------------------------
+
+
+def first_argmin(sc: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``jnp.unravel_index(jnp.argmin(sc), sc.shape)`` of a 2-D ``sc``, in
+    two steps: the first row holding the smallest entry, then its first
+    column.  The one argmin over all M*M entries took 29 s to compile for
+    a v5e at M = 4000, the two steps 4 s."""
+    i = jnp.argmin(jnp.min(sc, axis=1))
+    return i, jnp.argmin(sc[i])
 
 
 @functools.partial(jax.jit, static_argnames=("criterion",))
@@ -98,8 +116,7 @@ def merge_subclusters(
     def body(carry):
         st, labels, n_merges = carry
         sc = score(st)
-        flat = jnp.argmin(sc)
-        i, j = flat // m, flat % m
+        i, j = first_argmin(sc)
         # merge j into i (paper's update formulas)
         ni, nj = st.sizes[i], st.sizes[j]
         ci, cj = st.centers[i], st.centers[j]
@@ -141,16 +158,32 @@ def merge_gathered(per_site: SuffStats, cfg: VClusterConfig) -> MergeResult:
 # ---------------------------------------------------------------------------
 
 
+def border_order(own_d2: jax.Array, glabel: jax.Array) -> jax.Array:
+    """The points' order by (cluster, farthest first, index): the
+    permutation of ``jnp.lexsort((index, -own_d2, glabel))`` for distances
+    that are not NaN, as two stable sorts of int32 keys (farthest first,
+    then by cluster).  A distance is >= 0, so its float32 bits order as
+    its values do once subnormals are flushed to zero, as the float
+    comparisons of XLA's sort flush them.  At 200 sites x 25,000 points
+    the three-key lexsort took 67 s to compile for a v5e, two stable
+    sorts of float keys 21 s, of int32 keys 12 s."""
+    d = jnp.where(own_d2 < jnp.finfo(own_d2.dtype).tiny, 0.0, own_d2)
+    far = jnp.argsort(-jax.lax.bitcast_convert_type(d, jnp.int32), stable=True)
+    return far[jnp.argsort(glabel[far], stable=True)]
+
+
 def perturb_site(
     x: jax.Array,  # (n, D) site-local points
     point_slot: jax.Array,  # (n,) int32 — sub-cluster SLOT id per point
     merged: MergeResult,
     b: int,
-) -> tuple[jax.Array, SuffStats]:
+) -> tuple[jax.Array, SuffStats, PerturbCounts]:
     """Paper lines 13-24: move border candidates between global clusters when
     the global variance decreases.  Operates on this site's own points only,
     against the (replicated) global statistics; returns per-point global
-    slot labels and this site's locally-updated copy of the global stats.
+    slot labels, this site's locally-updated copy of the global stats, and
+    the scan's counts: its steps (M*b candidate slots), the live ones, and
+    the points it moved.
 
     Candidate selection: within each live global cluster, the b points of
     THIS site farthest from the global center ("find_border").  Move test
@@ -169,12 +202,11 @@ def perturb_site(
 
     # --- border candidates: top-b farthest per global cluster, this site ---
     # Each point is scored against its OWN global centre only, and the
-    # per-cluster top-b comes from one sort by (cluster, -distance,
-    # index), so nothing (n, M)-sized is built: at 200 sites x 20
-    # sub-clusters that matrix alone is n x 4000 floats per site.
+    # per-cluster top-b comes from ordering the points by (cluster,
+    # -distance, index), so nothing (n, M)-sized is built: at 200 sites x
+    # 20 sub-clusters that matrix alone is n x 4000 floats per site.
     own_d2 = jnp.sum((x - st.centers[glabel]) ** 2, axis=-1)  # (n,)
-    pos = jnp.arange(n, dtype=jnp.int32)
-    order = jnp.lexsort((pos, -own_d2, glabel))  # cluster-major, farthest first
+    order = border_order(own_d2, glabel)
     slot_ids = jnp.arange(m, dtype=jnp.int32)
     start = jnp.searchsorted(glabel[order], slot_ids, side="left")  # (M,)
     count = jnp.searchsorted(glabel[order], slot_ids, side="right") - start
@@ -219,7 +251,12 @@ def perturb_site(
     (sizes, centers, sse, glabel), moved = jax.lax.scan(
         move_one, carry0, (cand_flat, valid_flat)
     )
-    return glabel, SuffStats(sizes=sizes, centers=centers, sse=sse)
+    counts = PerturbCounts(
+        steps=jnp.int32(moved.shape[0]),
+        live=jnp.sum(valid_flat.astype(jnp.int32)),
+        moved=jnp.sum(moved.astype(jnp.int32)),
+    )
+    return glabel, SuffStats(sizes=sizes, centers=centers, sse=sse), counts
 
 
 # ---------------------------------------------------------------------------
@@ -251,8 +288,14 @@ def _site_local_batch(keys, xs, cfg: VClusterConfig):
 def _perturb_batch(xs, slots, merged: MergeResult, b: int):
     """Fused fan-out: border perturbation for every site in ONE vmapped
     dispatch (site-local by construction — the merged stats are
-    replicated, exactly as in the pooled driver)."""
-    return jax.vmap(lambda x, s: perturb_site(x, s, merged, b)[0])(xs, slots)
+    replicated, exactly as in the pooled driver).  Returns each site's
+    labels and its scan's ``PerturbCounts``."""
+
+    def one(x, s):
+        labels, _, counts = perturb_site(x, s, merged, b)
+        return labels, counts
+
+    return jax.vmap(one)(xs, slots)
 
 
 @functools.partial(jax.jit, static_argnames=("b",))
@@ -260,7 +303,12 @@ def _perturb_batch_many(xs, slots, merged: MergeResult, b: int):
     """Like ``_perturb_batch`` but with one MergeResult PER MEMBER
     (leaves stacked on a leading axis) — the cross-request fused waves
     of the serving layer carry each request's own merge result."""
-    return jax.vmap(lambda x, s, m: perturb_site(x, s, m, b)[0])(xs, slots, merged)
+
+    def one(x, s, m):
+        labels, _, counts = perturb_site(x, s, m, b)
+        return labels, counts
+
+    return jax.vmap(one)(xs, slots, merged)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -279,7 +327,7 @@ def vcluster_pooled(key: jax.Array, xs: jax.Array, cfg: VClusterConfig = VCluste
     point_slots = assigns + offsets  # (s, n) slot ids
 
     def site_perturb(x, slots):
-        lbl, _ = perturb_site(x, slots, merged, cfg.border_candidates)
+        lbl, _, _ = perturb_site(x, slots, merged, cfg.border_candidates)
         return lbl
 
     labels = jax.vmap(site_perturb)(xs, point_slots)
@@ -307,7 +355,7 @@ def vcluster_shard_map(mesh, axis: str, cfg: VClusterConfig = VClusterConfig()):
         merged = merge_gathered(gathered, cfg)
         site_idx = jax.lax.axis_index(axis)
         slots = assign + site_idx.astype(jnp.int32) * k
-        labels, _ = perturb_site(x, slots, merged, cfg.border_candidates)
+        labels, _, _ = perturb_site(x, slots, merged, cfg.border_candidates)
         return labels, merged
 
     fn = jax.shard_map(
@@ -352,6 +400,11 @@ def vcluster_site_jobs(
     ``batch_key``/``batched_fn`` hooks: under the ``batched`` execution
     backend the whole fan-out runs as ONE vmapped dispatch across the
     site axis, with the measured batch time apportioned per job.
+
+    Each stage's dispatch and the wait for its output is one span:
+    ``repro.vc.cluster``, ``repro.vc.merge`` (stat ``merges``) and
+    ``repro.vc.perturb`` (stats ``steps``, ``live`` and ``moved``: its
+    sites' ``PerturbCounts`` summed).
     """
     from repro.workflow.sitejob import SiteJob, timed, timed_batch
 
@@ -366,7 +419,8 @@ def vcluster_site_jobs(
 
     def cluster_fn(i):
         def fn():
-            return _site_local(keys[i], xs[i], cfg)
+            with span("repro.vc.cluster"):
+                return jax.block_until_ready(_site_local(keys[i], xs[i], cfg))
 
         return fn
 
@@ -377,7 +431,8 @@ def vcluster_site_jobs(
         # while the site data is pinned identical by the fuse signature
         idx = jnp.asarray([i for i, _ in bargs], dtype=jnp.int32)
         bkeys = jnp.stack([kk for _, kk in bargs])
-        assigns, st = _site_local_batch(bkeys, xs[idx], cfg)
+        with span("repro.vc.cluster"):
+            assigns, st = jax.block_until_ready(_site_local_batch(bkeys, xs[idx], cfg))
         return [
             (assigns[j], SuffStats(sizes=st.sizes[j], centers=st.centers[j], sse=st.sse[j]))
             for j in range(len(bargs))
@@ -403,7 +458,10 @@ def vcluster_site_jobs(
             centers=jnp.stack([st.centers for _, st in site_out]),
             sse=jnp.stack([st.sse for _, st in site_out]),
         )
-        return sync(per_site)
+        with span("repro.vc.merge") as sp:
+            merged = jax.block_until_ready(sync(per_site))
+            sp.set_metadata(merges=int(merged.n_merges))
+        return merged
 
     jobs.append(
         SiteJob(
@@ -414,11 +472,18 @@ def vcluster_site_jobs(
         )
     )
 
+    b = cfg.border_candidates
+
+    def count_stats(sp, counts: PerturbCounts) -> None:
+        sp.set_metadata(**{f: int(jnp.sum(v)) for f, v in counts._asdict().items()})
+
     def perturb_fn(i):
         def fn(site_out, merged):
             assign, _ = site_out
             slots = assign + jnp.int32(i * k)
-            labels, _ = perturb_site(xs[i], slots, merged, cfg.border_candidates)
+            with span("repro.vc.perturb") as sp:
+                labels, _, counts = jax.block_until_ready(perturb_site(xs[i], slots, merged, b))
+                count_stats(sp, counts)
             return labels
 
         return fn
@@ -428,15 +493,18 @@ def vcluster_site_jobs(
         assigns = jnp.stack([site_out[0] for site_out, _ in argss])
         slots = assigns + (idx * jnp.int32(k))[:, None]
         mergeds = [m for _, m in argss]
-        if all(m is mergeds[0] for m in mergeds):
-            # one engine run: every member shares the same "merge" dep —
-            # keep the exact broadcast path (bitwise-stable, what the
-            # cross-backend conformance suite pins)
-            labels = _perturb_batch(xs[idx], slots, mergeds[0], cfg.border_candidates)
-        else:
-            # cross-request merged wave: one MergeResult per member
-            merged = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *mergeds)
-            labels = _perturb_batch_many(xs[idx], slots, merged, cfg.border_candidates)
+        with span("repro.vc.perturb") as sp:
+            if all(m is mergeds[0] for m in mergeds):
+                # one engine run: every member shares the same "merge" dep —
+                # keep the exact broadcast path (bitwise-stable, what the
+                # cross-backend conformance suite pins)
+                out = _perturb_batch(xs[idx], slots, mergeds[0], b)
+            else:
+                # cross-request merged wave: one MergeResult per member
+                merged = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *mergeds)
+                out = _perturb_batch_many(xs[idx], slots, merged, b)
+            labels, counts = jax.block_until_ready(out)
+            count_stats(sp, counts)
         return [labels[j] for j in range(len(bargs))]
 
     for i in range(s):

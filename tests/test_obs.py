@@ -137,3 +137,36 @@ def test_compiles_are_counted_per_request_and_in_the_ledger():
     assert led["compiles"] == made.count
     assert led["compile_s"] == pytest.approx(made.seconds) and led["compile_s"] > 0
     assert [r["compiles"] for r in led["requests"]] == [made.count, 0]
+
+
+def test_a_vclustering_request_opens_its_stage_spans_with_their_stats(tmp_path):
+    import numpy as np
+
+    n_sites, k_local = 3, 4
+    rng = np.random.default_rng(0)
+    centres = np.array([[0.0, 0.0], [20.0, 0.0], [0.0, 20.0]])
+    pts = (centres[rng.integers(0, 3, 300)] + rng.normal(0, 0.5, (300, 2))).astype(np.float32)
+    svc = MiningService(backend="batched", n_sites=n_sites)
+    svc.register_dataset("pts", "points", dim=2)
+    svc.append_points("pts", pts)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        rid = svc.submit("t", "vclustering", "pts", {"k_local": k_local, "iters": 5})
+        svc.drain()
+    assert svc.poll(rid) == "done", svc.request(rid).error
+    res = svc.result(rid)
+    spans = _spans(tmp_path)
+    stage = {s["name"]: s for s in spans if s["name"].startswith("repro.vc.")}
+    assert set(stage) == {"repro.vc.cluster", "repro.vc.merge", "repro.vc.perturb"}
+    for s in stage.values():
+        assert s["outer"][:4] == ["repro.step", "repro.request", "repro.engine", "repro.job"]
+    assert stage["repro.vc.merge"]["stats"]["merges"] == int(res.merged.n_merges) > 0
+    perturb = stage["repro.vc.perturb"]["stats"]
+    b = 8  # VClusterConfig.border_candidates
+    assert perturb["steps"] == n_sites * n_sites * k_local * b
+    # each of the global clusters has at least b points at every site
+    assert perturb["live"] == n_sites * int(res.merged.n_global) * b
+    # well-separated clusters: no border point is nearer another cluster
+    assert perturb["moved"] == 0

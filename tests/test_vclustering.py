@@ -7,10 +7,16 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.kmeans import gap_statistic, kmeans
 from repro.core.vclustering import (
     VClusterConfig,
+    _site_local_batch,
+    border_order,
+    first_argmin,
+    merge_gathered,
+    perturb_site,
     vcluster_pooled,
 )
 from repro.data.synthetic import gaussian_mixture, split_sites
@@ -139,3 +145,52 @@ class TestShardMapDriver:
             env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         assert "SHARD_MAP_EQUIV_OK" in p.stdout, p.stdout + p.stderr
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_border_order_is_the_three_key_lexsort(seed):
+    # few distinct distances and clusters, so ties are the rule; zero,
+    # tiny and infinite distances too
+    rng = np.random.default_rng(seed)
+    own_d2 = rng.integers(0, 4, 500).astype(np.float32) * np.float32(0.5)
+    own_d2[rng.integers(0, 500, 20)] = rng.choice([1e-40, 3e38, np.inf], 20).astype(np.float32)
+    own_d2 = jnp.asarray(own_d2)
+    glabel = jnp.asarray(rng.integers(0, 6, 500).astype(np.int32))
+    want = jnp.lexsort((jnp.arange(500, dtype=jnp.int32), -own_d2, glabel))
+    assert np.array_equal(np.asarray(border_order(own_d2, glabel)), np.asarray(want))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_first_argmin_is_the_flat_argmin_unravelled(seed):
+    # few distinct values, an inf or two and a nan: ties are the rule
+    rng = np.random.default_rng(seed)
+    sc = rng.integers(0, 3, (40, 30)).astype(np.float32)
+    sc[rng.integers(0, 40, 3), rng.integers(0, 30, 3)] = np.inf
+    if seed == 2:
+        sc[7, 11] = np.nan
+    for m in (sc, np.full_like(sc, np.inf)):
+        want = jnp.unravel_index(jnp.argmin(jnp.asarray(m)), m.shape)
+        assert tuple(map(int, first_argmin(jnp.asarray(m)))) == tuple(map(int, want))
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_perturb_site_counts_its_steps_live_candidates_and_moves(seed):
+    # overlapping clusters, so that border points move
+    pts, _ = planted(seed=seed, n=1200, spread=3.0, sigma=1.0)
+    xs = jnp.asarray(split_sites(pts, 3, seed=seed))
+    cfg = VClusterConfig(k_local=6, kmeans_iters=10, border_candidates=4)
+    assigns, stats = _site_local_batch(jax.random.split(jax.random.PRNGKey(seed), 3), xs, cfg)
+    merged = merge_gathered(stats, cfg)
+    roots, m = np.asarray(merged.labels), merged.stats.n_slots
+    moved_in_all = 0
+    for i in range(3):
+        slots = assigns[i] + i * cfg.k_local
+        labels, _, counts = perturb_site(xs[i], slots, merged, cfg.border_candidates)
+        before = roots[np.asarray(slots)]
+        sizes = np.bincount(before, minlength=m)
+        assert int(counts.steps) == m * cfg.border_candidates
+        assert int(counts.live) == int(np.minimum(sizes, cfg.border_candidates).sum())
+        # a border point moves once at most, so the moves are the changed labels
+        assert int(counts.moved) == int((np.asarray(labels) != before).sum())
+        moved_in_all += int(counts.moved)
+    assert moved_in_all > 0
