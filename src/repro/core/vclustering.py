@@ -65,10 +65,13 @@ class MergeResult(NamedTuple):
 
 
 class PerturbCounts(NamedTuple):
-    """What one site's border-perturbation scan did (int32 scalars)."""
+    """What one site's border perturbation did (int32 scalars).  The loop
+    runs over the live candidates only; the empty slots of the (M, b)
+    candidate table, each an exact no-op, are never stepped through."""
 
-    steps: jax.Array  # candidate slots the scan stepped through, live or not
-    live: jax.Array  # steps that carried a border candidate
+    steps: jax.Array  # candidate slots of the (M, b) table, live or not
+    live: jax.Array  # slots that carry a border candidate
+    trips: jax.Array  # loop iterations this site ran: its live candidates
     moved: jax.Array  # points moved to another global cluster
 
 
@@ -182,8 +185,17 @@ def perturb_site(
     the global variance decreases.  Operates on this site's own points only,
     against the (replicated) global statistics; returns per-point global
     slot labels, this site's locally-updated copy of the global stats, and
-    the scan's counts: its steps (M*b candidate slots), the live ones, and
-    the points it moved.
+    the counts: the M*b candidate slots, the live ones, the loop's trips
+    and the points it moved.
+
+    The candidates are visited in slot-major, then rank order, as a scan
+    over all M*b slots would visit them, but the loop steps through the
+    live ones only: they are first packed into a dense prefix, and a
+    ``while_loop`` runs while ``t < live``.  A slot without a candidate
+    would leave every statistic and label as it was, so skipping it gives
+    the same answers bit for bit.  Under ``vmap`` the loop runs as many
+    trips as the site with the most live candidates; a site that has
+    finished keeps its carry.
 
     Candidate selection: within each live global cluster, the b points of
     THIS site farthest from the global center ("find_border").  Move test
@@ -216,6 +228,15 @@ def perturb_site(
 
     cand_flat = cand_idx.reshape(-1)  # (M*b,)
     valid_flat = cand_valid.reshape(-1)
+    n_slots = cand_flat.shape[0]
+
+    # the live candidates packed into a dense prefix, in the table's order;
+    # an empty slot scatters out of bounds and is dropped (one entry at
+    # least, so that b = 0 still traces a loop body, which never runs)
+    pos = jnp.cumsum(valid_flat.astype(jnp.int32)) - 1
+    live_idx = jnp.zeros((max(n_slots, 1),), cand_flat.dtype)
+    live_idx = live_idx.at[jnp.where(valid_flat, pos, n_slots)].set(cand_flat, mode="drop")
+    n_live = jnp.sum(valid_flat.astype(jnp.int32))
 
     def move_one(carry, ci):
         sizes, centers, sse, glabel = carry
@@ -247,15 +268,16 @@ def perturb_site(
         carry = jax.lax.cond(do, apply, lambda a: a, (sizes, centers, sse, glabel))
         return carry, do
 
+    def live_step(loop):
+        t, carry, moved = loop
+        carry, do = move_one(carry, (live_idx[t], t < n_live))
+        return t + 1, carry, moved + do.astype(jnp.int32)
+
     carry0 = (st.sizes, st.centers, st.sse, glabel)
-    (sizes, centers, sse, glabel), moved = jax.lax.scan(
-        move_one, carry0, (cand_flat, valid_flat)
+    trips, (sizes, centers, sse, glabel), moved = jax.lax.while_loop(
+        lambda loop: loop[0] < n_live, live_step, (jnp.int32(0), carry0, jnp.int32(0))
     )
-    counts = PerturbCounts(
-        steps=jnp.int32(moved.shape[0]),
-        live=jnp.sum(valid_flat.astype(jnp.int32)),
-        moved=jnp.sum(moved.astype(jnp.int32)),
-    )
+    counts = PerturbCounts(steps=jnp.int32(n_slots), live=n_live, trips=trips, moved=moved)
     return glabel, SuffStats(sizes=sizes, centers=centers, sse=sse), counts
 
 
@@ -289,7 +311,7 @@ def _perturb_batch(xs, slots, merged: MergeResult, b: int):
     """Fused fan-out: border perturbation for every site in ONE vmapped
     dispatch (site-local by construction — the merged stats are
     replicated, exactly as in the pooled driver).  Returns each site's
-    labels and its scan's ``PerturbCounts``."""
+    labels and its ``PerturbCounts``."""
 
     def one(x, s):
         labels, _, counts = perturb_site(x, s, merged, b)
@@ -403,8 +425,8 @@ def vcluster_site_jobs(
 
     Each stage's dispatch and the wait for its output is one span:
     ``repro.vc.cluster``, ``repro.vc.merge`` (stat ``merges``) and
-    ``repro.vc.perturb`` (stats ``steps``, ``live`` and ``moved``: its
-    sites' ``PerturbCounts`` summed).
+    ``repro.vc.perturb`` (stats ``steps``, ``live``, ``trips`` and
+    ``moved``: its sites' ``PerturbCounts`` summed).
     """
     from repro.workflow.sitejob import SiteJob, timed, timed_batch
 

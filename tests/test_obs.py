@@ -168,5 +168,7 @@ def test_a_vclustering_request_opens_its_stage_spans_with_their_stats(tmp_path):
     assert perturb["steps"] == n_sites * n_sites * k_local * b
     # each of the global clusters has at least b points at every site
     assert perturb["live"] == n_sites * int(res.merged.n_global) * b
+    # the loop steps through the live candidates only
+    assert perturb["trips"] == perturb["live"]
     # well-separated clusters: no border point is nearer another cluster
     assert perturb["moved"] == 0

@@ -10,8 +10,11 @@ import numpy as np
 import pytest
 
 from repro.core.kmeans import gap_statistic, kmeans
+from repro.core.stats import SuffStats, stats_from_assignment
 from repro.core.vclustering import (
     VClusterConfig,
+    _perturb_batch,
+    _perturb_batch_many,
     _site_local_batch,
     border_order,
     first_argmin,
@@ -190,7 +193,132 @@ def test_perturb_site_counts_its_steps_live_candidates_and_moves(seed):
         sizes = np.bincount(before, minlength=m)
         assert int(counts.steps) == m * cfg.border_candidates
         assert int(counts.live) == int(np.minimum(sizes, cfg.border_candidates).sum())
+        assert int(counts.trips) == int(counts.live)
         # a border point moves once at most, so the moves are the changed labels
         assert int(counts.moved) == int((np.asarray(labels) != before).sum())
         moved_in_all += int(counts.moved)
     assert moved_in_all > 0
+
+
+def full_scan_perturb(x, point_slot, merged, b):
+    """``perturb_site`` as it was before its loop ran over the live
+    candidates only: a ``lax.scan`` over all M*b candidate slots, the
+    empty ones included.  The oracle of the live loop."""
+    n, d = x.shape
+    m = merged.stats.n_slots
+    glabel = merged.labels[point_slot]
+    st = merged.stats
+    alive = st.sizes > 0
+    own_d2 = jnp.sum((x - st.centers[glabel]) ** 2, axis=-1)
+    order = border_order(own_d2, glabel)
+    slot_ids = jnp.arange(m, dtype=jnp.int32)
+    start = jnp.searchsorted(glabel[order], slot_ids, side="left")
+    count = jnp.searchsorted(glabel[order], slot_ids, side="right") - start
+    rank = jnp.arange(min(b, n), dtype=jnp.int32)[None, :]
+    cand_valid = rank < count[:, None]
+    cand_idx = order[jnp.where(cand_valid, start[:, None] + rank, 0)]
+    cand_flat = cand_idx.reshape(-1)
+    valid_flat = cand_valid.reshape(-1)
+
+    def move_one(carry, ci):
+        sizes, centers, sse, glabel = carry
+        idx, ok = ci
+        xi = x[idx]
+        g = glabel[idx]
+        dg2 = jnp.sum((xi - centers[g]) ** 2)
+        d2 = jnp.sum((xi[None, :] - centers) ** 2, axis=-1)
+        d2 = jnp.where(alive & (sizes > 0), d2, jnp.inf)
+        d2 = d2.at[g].set(jnp.inf)
+        j = jnp.argmin(d2).astype(jnp.int32)
+        dj2 = d2[j]
+        ng, nj = sizes[g], sizes[j]
+        gain_remove = jnp.where(ng > 1, ng / jnp.maximum(ng - 1.0, 1e-30) * dg2, 0.0)
+        cost_add = nj / (nj + 1.0) * dj2
+        do = ok & (ng > 1) & jnp.isfinite(dj2) & (cost_add < gain_remove)
+
+        def apply(args):
+            sizes, centers, sse, glabel = args
+            cg_new = jnp.where(ng > 1, (sizes[g] * centers[g] - xi) / jnp.maximum(ng - 1.0, 1e-30), centers[g])
+            cj_new = (sizes[j] * centers[j] + xi) / (nj + 1.0)
+            sizes = sizes.at[g].add(-1.0).at[j].add(1.0)
+            centers = centers.at[g].set(cg_new).at[j].set(cj_new)
+            sse = sse.at[g].add(-gain_remove).at[j].add(cost_add)
+            glabel = glabel.at[idx].set(j)
+            return sizes, centers, sse, glabel
+
+        carry = jax.lax.cond(do, apply, lambda a: a, (sizes, centers, sse, glabel))
+        return carry, do
+
+    carry0 = (st.sizes, st.centers, st.sse, glabel)
+    (sizes, centers, sse, glabel), moved = jax.lax.scan(move_one, carry0, (cand_flat, valid_flat))
+    counts = (jnp.int32(moved.shape[0]), jnp.sum(valid_flat.astype(jnp.int32)),
+              jnp.sum(moved.astype(jnp.int32)))
+    return glabel, SuffStats(sizes=sizes, centers=centers, sse=sse), counts
+
+
+def uneven_sites(seed, n_sites=4, n=240, n_comp=4, b=4):
+    """Overlapping planted 2-D clusters split so that the sites' live
+    candidate counts differ: true cluster 2 is absent from site 1, and site
+    2 holds fewer than b points of true cluster 3.  Each site's
+    sub-clusters are its points' true clusters, so the global clusters
+    are the true ones."""
+    pts, lab = planted(seed=seed, n=4 * n_sites * n, n_comp=n_comp, spread=3.0, sigma=1.0)
+    rng = np.random.default_rng(seed)
+    xs, assigns = [], []
+    for i in range(n_sites):
+        few = np.flatnonzero(lab == 3)[: b - 2] if i == 2 else np.zeros(0, int)
+        pool = np.flatnonzero(lab != {1: 2, 2: 3}.get(i, -1))
+        pick = np.concatenate([few, rng.choice(pool, n - len(few), replace=False)])
+        xs.append(pts[pick])
+        assigns.append(lab[pick].astype(np.int32))
+    xs, assigns = jnp.asarray(np.stack(xs)), jnp.asarray(np.stack(assigns))
+    stats = jax.vmap(lambda x, a: stats_from_assignment(x, a, n_comp))(xs, assigns)
+    slots = assigns + (jnp.arange(n_sites, dtype=jnp.int32) * n_comp)[:, None]
+    return xs, slots, stats
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_live_loop_equals_the_full_scan_bit_for_bit(seed):
+    b, n_comp = 4, 4
+    xs, slots, stats = uneven_sites(seed, n_comp=n_comp, b=b)
+    cfg = VClusterConfig(k_local=n_comp, threshold_factor=0.5)
+    merged = merge_gathered(stats, cfg)
+    other = merge_gathered(stats, cfg._replace(criterion="merged_var", threshold_factor=2.0))
+    assert int(merged.n_global) == n_comp
+    # the sites' live counts differ: an absent cluster, and one below b
+    glabel = np.asarray(merged.labels)[np.asarray(slots)]
+    alive = np.flatnonzero(np.asarray(merged.stats.sizes) > 0)
+    per_site = np.stack([np.bincount(g, minlength=merged.stats.n_slots)[alive] for g in glabel])
+    assert (per_site == 0).any() and ((per_site > 0) & (per_site < b)).any()
+    assert len(set(np.minimum(per_site, b).sum(axis=1))) > 1
+
+    def equal(a, b_):
+        return all(np.array_equal(np.asarray(u), np.asarray(v)) for u, v in zip(a, b_, strict=True))
+
+    def got(labels, st, c):
+        return labels, *st, c.steps, c.live, c.trips, c.moved
+
+    def want(labels, st, c):  # the full scan's, whose trips would be its live ones
+        steps, live, moved = c
+        return labels, *st, steps, live, live, moved
+
+    moved = 0
+    for m in (merged, other):
+        # site by site
+        for i in range(len(xs)):
+            w = full_scan_perturb(xs[i], slots[i], m, b)
+            assert equal(got(*perturb_site(xs[i], slots[i], m, b)), want(*w))
+            moved += int(w[2][2])
+        # the batched loop, where each site stops at its own live count
+        w = want(*jax.vmap(lambda x, s: full_scan_perturb(x, s, m, b))(xs, slots))
+        assert equal(got(*jax.jit(jax.vmap(lambda x, s: perturb_site(x, s, m, b)))(xs, slots)), w)
+        labels, counts = _perturb_batch(xs, slots, m, b)
+        assert equal(got(labels, (), counts), w[:1] + w[4:])
+    assert moved > 0
+    # one merge result per member: each site under both merges in one wave
+    assert not np.array_equal(np.asarray(merged.labels), np.asarray(other.labels))
+    both = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *([merged] * len(xs) + [other] * len(xs)))
+    labels, counts = _perturb_batch_many(jnp.concatenate([xs, xs]), jnp.concatenate([slots, slots]), both, b)
+    w = [want(*full_scan_perturb(xs[i], slots[i], m, b)) for m in (merged, other) for i in range(len(xs))]
+    w = [np.stack(col) for col in zip(*w, strict=True)]
+    assert equal(got(labels, (), counts), w[:1] + w[4:])
