@@ -86,9 +86,3 @@ def split_transactions(dense: np.ndarray, n_sites: int, seed: int = 0) -> list[n
     """Split a dense transaction DB into per-site shards (uneven tail ok)."""
     return [dense[s] for s in split_indices(len(dense), n_sites, seed)]
 
-
-def token_batch(seed: int, batch: int, seq_len: int, vocab: int) -> dict[str, np.ndarray]:
-    """Synthetic LM batch (tokens + next-token labels) for examples/tests."""
-    rng = np.random.default_rng(seed)
-    toks = rng.integers(0, vocab, size=(batch, seq_len + 1), dtype=np.int32)
-    return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}

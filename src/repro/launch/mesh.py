@@ -18,62 +18,19 @@ What lives here, bottom-up:
     wire: variable-length bytes (then packed pytrees) gathered across
     processes; the ONLY cross-process traffic the multihost backend
     performs, wave-fused so collectives scale with ready waves.
-  * :func:`make_production_mesh` / :func:`make_variant_mesh` /
-    :func:`make_test_mesh`, and the ``HW`` roofline constants — the
-    scale-out/dry-run harness meshes (16x16-pod shapes) used by the
-    roofline table and capacity notes, not by the mining runtime.
+  * :func:`enable_compile_cache` — the persistent compile cache every
+    entry point turns on first.
 
 Everything is kept as FUNCTIONS (never module-level constants) so
-importing this module never touches jax device state — the dry-run must
-set XLA_FLAGS=--xla_force_host_platform_device_count BEFORE first jax
-init, and ``init_multihost`` must run before the first backend query.
+importing this module never touches jax device state — a multi-device
+CPU run must set XLA_FLAGS=--xla_force_host_platform_device_count
+BEFORE first jax init, and ``init_multihost`` must run before the first
+backend query.
 """
 
 from __future__ import annotations
 
 import jax
-
-
-# XLA flag set for real-GPU deployments (jax gpu_performance_tips):
-# triton softmax fusion + any-shape triton GEMMs cut kernel-launch
-# overhead on the mining matmuls; async collectives + the latency-hiding
-# scheduler overlap the grid's cross-site synchronization with compute.
-GPU_XLA_FLAGS = (
-    "--xla_gpu_enable_triton_softmax_fusion=true "
-    "--xla_gpu_triton_gemm_any=True "
-    "--xla_gpu_enable_async_collectives=true "
-    "--xla_gpu_enable_latency_hiding_scheduler=true "
-    "--xla_gpu_enable_highest_priority_async_stream=true "
-)
-
-
-def tuned_platform(platform: str | None = None) -> str:
-    """Select the jax platform and apply the tuned XLA flag set for it —
-    the process-entry companion of the kernel autotuner (blocks tune the
-    Pallas tile shapes; this tunes what XLA does around them).
-
-    ``platform=None`` keeps whatever backend jax would pick and only
-    applies flags when that backend is GPU.  Like every XLA flag, this
-    only takes effect BEFORE the first jax computation/backend query —
-    call it first thing in ``main()`` (the benchmark entry points
-    ``bench_kernels``/``bench_runtime`` do).  On CPU/TPU it is a no-op
-    beyond the optional platform pin, so the benchmarks call it
-    unconditionally and real-GPU deployments get the tuned flags for
-    free.  Returns the platform name it settled on.
-    """
-    import os
-
-    if platform is not None:
-        if platform not in ("cpu", "gpu", "tpu"):
-            raise ValueError(f"unknown platform {platform!r} (want cpu|gpu|tpu)")
-        jax.config.update("jax_platform_name", platform)
-    if platform == "gpu" or (platform is None and _probable_backend() == "gpu"):
-        existing = os.environ.get("XLA_FLAGS", "")
-        missing = [f for f in GPU_XLA_FLAGS.split() if f.split("=")[0] not in existing]
-        if missing:
-            os.environ["XLA_FLAGS"] = (existing + " " + " ".join(missing)).strip()
-        return "gpu"
-    return platform or _probable_backend()
 
 
 def enable_compile_cache() -> str:
@@ -96,55 +53,6 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
     return jax.config.jax_compilation_cache_dir
-
-
-def _probable_backend() -> str:
-    """The backend jax will (or did) pick, WITHOUT forcing backend init
-    when the answer is already knowable from the environment — XLA_FLAGS
-    applied after init are dead letters, so :func:`tuned_platform` must
-    not itself trigger init while probing."""
-    import os
-
-    env = os.environ.get("JAX_PLATFORMS", "") or os.environ.get("JAX_PLATFORM_NAME", "")
-    if env:
-        return env.split(",")[0].strip().lower()
-    if os.environ.get("CUDA_VISIBLE_DEVICES") not in (None, "", "-1") or os.path.exists(
-        "/dev/nvidia0"
-    ):
-        return "gpu"
-    return jax.default_backend()
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips).
-
-    Axes: `pod` is the DCN-crossing grid-site axis (the paper's "site"),
-    `data` is intra-pod DP/FSDP, `model` is TP/EP.  The dry-run environment
-    exposes 512 placeholder devices; the single-pod mesh uses the first 256.
-    """
-    import math
-
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    need = math.prod(shape)
-    devs = jax.devices()[:need]
-    return jax.make_mesh(shape, axes, devices=devs)
-
-
-def make_variant_mesh(name: str, *, multi_pod: bool = False):
-    """Hillclimbing mesh variants (same chip counts as production).
-
-    'moe2d': (data, expert, model) = (16, 8, 2) — factorises the 256-chip
-    pod so coarse-expert MoEs (mixtral: 8 experts) get true expert
-    parallelism instead of TP-within-expert (§Perf iteration)."""
-    if name == "moe2d":
-        shape = (2, 16, 8, 2) if multi_pod else (16, 8, 2)
-        axes = ("pod", "data", "expert", "model") if multi_pod else ("data", "expert", "model")
-        import math
-
-        devs = jax.devices()[: math.prod(shape)]
-        return jax.make_mesh(shape, axes, devices=devs)
-    raise KeyError(name)
 
 
 def init_multihost(
@@ -303,21 +211,3 @@ def make_site_mesh(n_sites: int, axis: str = "sites"):
     if n_sites < 1 or len(devs) < n_sites:
         return None
     return jax.make_mesh((n_sites,), (axis,), devices=devs[:n_sites])
-
-
-def make_test_mesh(n_data: int = 2, n_model: int = 2, n_pods: int = 0):
-    """Small mesh for multi-device CPU tests (subprocesses set
-    xla_force_host_platform_device_count accordingly)."""
-    if n_pods:
-        return jax.make_mesh((n_pods, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
-
-
-# TPU v5e hardware model used by the roofline analysis (per chip).
-HW = {
-    "peak_flops_bf16": 197e12,  # FLOP/s
-    "hbm_bw": 819e9,  # B/s
-    "ici_bw": 50e9,  # B/s per link (~ per-direction)
-    "chips_per_pod": 256,
-    "dcn_bw": 6.25e9,  # B/s per host NIC-ish; used for pod-crossing notes
-}

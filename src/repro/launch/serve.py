@@ -38,8 +38,8 @@ arXiv:1412.2673) is a bursty stream of arrivals from many users.
     execution group ran) in the same spirit as the engine's
     ``RunReport``, JSON-serializable for the CI smoke's artifact.
   * **spans** — ``repro.step`` (one tick), ``repro.request`` (one
-    execution group or fused bucket) and ``repro.split`` (the site split:
-    a row gather of the packed table)
+    execution group or fused bucket) and ``repro.split`` (the site split,
+    ``workflow.datasets.Dataset.sites``)
     on the profiler's clock (``repro.obs``); see ``docs/serving.md``.
 
 CLI driver (bursty synthetic multi-tenant trace; exits non-zero when any
@@ -58,17 +58,16 @@ import sys
 import time
 from collections import OrderedDict, deque
 from contextlib import contextmanager
-from dataclasses import dataclass, field
 from typing import Any
 
 import numpy as np
 
-from repro.core.apriori import DeltaApriori
 from repro.data.synthetic import gaussian_mixture, ibm_transactions
 from repro.launch.mesh import enable_compile_cache
 from repro.obs import compiles, span
 from repro.runtime.cache import ResultCache, params_key
 from repro.runtime.gridruntime import GridRuntime
+from repro.workflow.datasets import Dataset
 from repro.workflow.registry import app_names, get_workload, workloads
 from repro.workflow.requests import (
     MiningRequest,
@@ -84,60 +83,19 @@ from repro.workflow.sitejob import SiteJob, timed, timed_batch
 APPS = app_names()
 
 
-@dataclass
-class _Dataset:
-    """Per-dataset incremental state the service maintains across appends.
-
-    A transactions dataset is held packed, never dense: the delta-Apriori
-    state keeps every appended batch as a packed ``TransactionDB`` on the
-    device, and :meth:`packed` gives the whole table as one packed host
-    array, copied from the device once per version.  A grid split
-    gathers each site's rows from that table; no dense copy is kept."""
-
-    name: str
-    kind: str  # "transactions" | "points"
-    version: int = 0
-    # transactions: the delta-Apriori state (the packed batches) plus the
-    # packed host table of the version it was built for
-    n_items: int | None = None
-    delta: DeltaApriori | None = None
-    _packed: np.ndarray | None = field(default=None, init=False, repr=False)
-    _packed_version: int = field(default=-1, init=False, repr=False)
-    # points: appended (n, dim) batches plus per-k warm-start centroids
-    dim: int | None = None
-    pt_batches: list = field(default_factory=list)
-    warm_centers: dict = field(default_factory=dict)  # k -> np.ndarray (k, dim)
-
-    def pooled_points(self) -> np.ndarray:
-        return np.concatenate(self.pt_batches, axis=0)
-
-    def packed(self) -> np.ndarray:
-        """The whole transactions table, packed: (n_tx, W) uint32 on the
-        host, rows in append order.  Rebuilt on the first call after an
-        append; the host concatenation keeps every compile off the
-        dataset's length."""
-        if self._packed_version != self.version:
-            parts = [np.asarray(db.packed) for db in self.delta.batches]
-            table = parts[0] if len(parts) == 1 else np.concatenate(parts)
-            # rows contiguous for the split's gathers: a TPU's host copy of
-            # a device array can be column-major
-            self._packed = np.ascontiguousarray(table)
-            self._packed_version = self.version
-        return self._packed
-
-
 class MiningService:
     """In-process multi-tenant mining service over :class:`GridRuntime`.
 
-    One instance owns the datasets, the tenant queues, the result cache
-    and the runtime; :meth:`step` is the scheduler tick — a fair pick of
-    queued requests, coalesced by execution key, served from cache or
-    executed through the engine's execution backend.
+    One instance owns the datasets (``workflow.datasets.Dataset``), the
+    tenant queues, the result cache and the runtime; :meth:`step` is the
+    scheduler tick — a fair pick of queued requests, coalesced by
+    execution key, served from cache or executed through the engine's
+    execution backend.  ``count_backend`` and ``use_kernel`` configure the
+    runtime, which is their one holder.
     """
 
     def __init__(
         self,
-        runtime: GridRuntime | None = None,
         backend: str = "batched",
         n_sites: int = 4,
         max_depth: int = 64,
@@ -149,24 +107,20 @@ class MiningService:
         fuse_requests: bool = True,
         failure_memo_capacity: int = 128,
     ):
-        if runtime is None:
-            runtime = GridRuntime(
-                backend=backend,
-                sync="pooled",
-                use_kernel=use_kernel,
-                count_backend=count_backend,
-            )
-        self.runtime = runtime
-        self.backend_name = runtime.engine.backend.name
-        self.n_sites = int(n_sites)
-        self.use_kernel = use_kernel
-        self.count_backend = count_backend
+        self.runtime = GridRuntime(
+            backend=backend,
+            sync="pooled",
+            use_kernel=use_kernel,
+            count_backend=count_backend,
+        )
+        self.backend_name = self.runtime.engine.backend.name
+        self.n_sites = int(n_sites)  # a grid request's default site count
         self.queues = TenantQueues(max_depth=max_depth, weights=weights)
         self.cache = ResultCache(cache_capacity)
         self._ids = request_ids()
         self._requests: dict[int, MiningRequest] = {}
         self._results: dict[int, Any] = {}
-        self._datasets: dict[str, _Dataset] = {}
+        self._datasets: dict[str, Dataset] = {}
         self._clock = clock
         self.executions = 0  # execution groups actually run (fused or not)
         self.coalesced = 0  # requests served by another request's run
@@ -208,50 +162,24 @@ class MiningService:
         self, name: str, kind: str = "transactions", *, n_items: int | None = None,
         dim: int | None = None,
     ) -> None:
-        if kind not in ("transactions", "points"):
-            raise ValueError(f"unknown dataset kind {kind!r}")
         if name in self._datasets:
             raise ValueError(f"dataset {name!r} already registered")
-        if kind == "transactions":
-            if n_items is None:
-                raise ValueError("transactions dataset needs n_items")
-            ds = _Dataset(name=name, kind=kind, n_items=int(n_items),
-                          delta=DeltaApriori(int(n_items), backend=self.count_backend))
-        else:
-            if dim is None:
-                raise ValueError("points dataset needs dim")
-            ds = _Dataset(name=name, kind=kind, dim=int(dim))
-        self._datasets[name] = ds
+        self._datasets[name] = Dataset(name, kind, n_items=n_items, dim=dim,
+                                       count_backend=self.runtime.count_backend)
 
-    def _dataset(self, name: str) -> _Dataset:
+    def _dataset(self, name: str) -> Dataset:
         try:
             return self._datasets[name]
         except KeyError:
             raise KeyError(f"unknown dataset {name!r}; register_dataset first") from None
 
     def append_transactions(self, name: str, dense_batch: np.ndarray) -> int:
-        """Append one dense bool (n_tx, n_items) batch; folds into the
-        delta-Apriori state and bumps ``version``.  Returns the version."""
-        ds = self._dataset(name)
-        if ds.kind != "transactions":
-            raise ValueError(f"dataset {name!r} holds points, not transactions")
-        dense = np.asarray(dense_batch, dtype=bool)
-        ds.delta.append(dense)
-        ds.version = ds.delta.version
-        return ds.version
+        """``Dataset.append_transactions`` on dataset ``name``."""
+        return self._dataset(name).append_transactions(dense_batch)
 
     def append_points(self, name: str, points: np.ndarray) -> int:
-        """Append one (n, dim) point batch; bumps ``version``.  Previous
-        per-k centroids are KEPT — they seed the next warm-started fit."""
-        ds = self._dataset(name)
-        if ds.kind != "points":
-            raise ValueError(f"dataset {name!r} holds transactions, not points")
-        pts = np.asarray(points, dtype=np.float32)
-        if pts.ndim != 2 or pts.shape[1] != ds.dim:
-            raise ValueError(f"expected (n, {ds.dim}) points, got {pts.shape}")
-        ds.pt_batches.append(pts)
-        ds.version += 1
-        return ds.version
+        """``Dataset.append_points`` on dataset ``name``."""
+        return self._dataset(name).append_points(points)
 
     def dataset_version(self, name: str) -> int:
         return self._dataset(name).version
@@ -420,6 +348,20 @@ class MiningService:
 
     # -- execution ------------------------------------------------------------
 
+    def _params(self, req: MiningRequest) -> dict:
+        """The request's params resolved against its workload's schema,
+        with the service's ``n_sites`` where a grid request left it unset:
+        the one dict the split, the fusion signature and the run all read."""
+        p = get_workload(req.app).resolve(req.params)
+        if "n_sites" in p and p["n_sites"] is None:
+            p["n_sites"] = self.n_sites
+        return p
+
+    def _split(self, ds: Dataset, p: dict):
+        """A grid request's per-site data, inside the ``repro.split`` span."""
+        with span("repro.split"):
+            return ds.sites(p["n_sites"], p["split_seed"])
+
     def _fuse_signature(self, rep: MiningRequest):
         """The workload's cross-request batch signature for one execution
         group's representative, or None when the group must run solo
@@ -430,10 +372,7 @@ class MiningService:
         spec = get_workload(rep.app)
         if spec.exec_batch_key is None:
             return None
-        p = spec.resolve(rep.params)
-        if "n_sites" in p and p["n_sites"] is None:
-            p = {**p, "n_sites": self.n_sites}
-        return spec.exec_batch_key(self._datasets[rep.dataset], p)
+        return spec.exec_batch_key(self._datasets[rep.dataset], self._params(rep))
 
     def _fuse_buckets(self, pending) -> list[list]:
         """Bucket the wave's pending execution groups: groups sharing
@@ -517,7 +456,7 @@ class MiningService:
         if fused and spec.finalize is not None:
             # serial execution finalizes inside _execute; the fused path
             # folds state back here, per group in wave order
-            spec.finalize(self._datasets[rep.dataset], spec.resolve(rep.params), value)
+            spec.finalize(self._datasets[rep.dataset], self._params(rep), value)
         self.cache.put(ckey, value)
         self.executions += 1
         if fused:
@@ -539,12 +478,8 @@ class MiningService:
         finished: list[int] = []
         with self._request_span([r for _, _, reqs in bucket for r in reqs]):
             if spec.runner == "grid":
-                datas, plists = [], []
-                for rep in reps:
-                    p = spec.resolve(rep.params)
-                    with span("repro.split"):
-                        datas.append(spec.site_split(ds, p, self))
-                    plists.append(spec.grid_params(p, self))
+                plists = [self._params(rep) for rep in reps]
+                datas = [self._split(ds, p) for p in plists]
                 runs = self.runtime.run_many(reps[0].app, datas, plists)
                 values = [(r.result, r.compute_s, r.backend) for r in runs]
             else:
@@ -568,9 +503,9 @@ class MiningService:
 
         bfn = timed_batch(fused, measured)
         jobs = []
+        ctx = self.runtime.context(measured)
         for j, rep in enumerate(reps):
-            p = spec.resolve(rep.params)
-            fn = spec.local_fn(ds, p, self)
+            fn = spec.local_fn(ds, self._params(rep), ctx)
             name = f"r{j}/{rep.app}"
             jobs.append(SiteJob(name=name, fn=timed(fn, measured, name),
                                 batch_key="local", batched_fn=bfn, batch_arg=fn))
@@ -586,23 +521,20 @@ class MiningService:
         """Run one representative request; returns (result, measured
         device compute seconds, backend name).  Entirely table-driven off
         the workload registry: local (delta-served) workloads run their
-        ``local_fn`` as a single ledgered job, grid workloads split the
-        dataset with the spec's ``site_split`` and go through the generic
-        ``GridRuntime.run`` — no per-app branches, so a registered app
-        can NEVER reach an "unknown app" dead end here (submit already
-        proved it is registered)."""
+        ``local_fn`` as a single ledgered job, grid workloads run the
+        generic ``GridRuntime.run`` on the dataset's site split — no
+        per-app branches, so a registered app can NEVER reach an "unknown
+        app" dead end here (submit already proved it is registered)."""
         spec = get_workload(req.app)
         ds = self._datasets[req.dataset]
-        p = spec.resolve(req.params)
+        p = self._params(req)
         if spec.runner == "local":
-            fn = spec.local_fn(ds, p, self)
+            fn = spec.local_fn(ds, p, self.runtime.context({}))
             value, compute_s, backend = self._run_single(req, fn)
             if spec.finalize is not None:
                 spec.finalize(ds, p, value)
             return value, compute_s, backend
-        with span("repro.split"):
-            data = spec.site_split(ds, p, self)
-        run = self.runtime.run(req.app, data, spec.grid_params(p, self))
+        run = self.runtime.run(req.app, self._split(ds, p), p)
         return run.result, run.report.compute_s, run.backend
 
     def _run_single(self, req: MiningRequest, fn) -> tuple[Any, float, str]:

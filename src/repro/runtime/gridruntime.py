@@ -252,6 +252,17 @@ class GridRuntime:
 
     # -- applications --------------------------------------------------------
 
+    def context(self, measured: dict[str, float]) -> RunContext:
+        """What a workload's builder (or the service's ``local_fn``) may use
+        from this runtime: the measured-times dict its jobs feed, the
+        count backend, the kernel toggle and the sync strategy."""
+        return RunContext(
+            measured=measured,
+            count_backend=self.count_backend,
+            use_kernel=self.use_kernel,
+            cluster_sync=self._cluster_sync,
+        )
+
     def _finish_run(self, jobs, rep: RunReport, result, measured, sync_mode: str) -> RuntimeRun:
         """Attach the measured-time-calibrated analytical bounds to a run.
         The specs carry the sites the placement policy ACTUALLY chose
@@ -299,12 +310,7 @@ class GridRuntime:
             )
         p = spec.resolve(params)
         measured: dict[str, float] = {}
-        ctx = RunContext(
-            measured=measured,
-            count_backend=self.count_backend,
-            use_kernel=self.use_kernel,
-            cluster_sync=self._cluster_sync,
-        )
+        ctx = self.context(measured)
         with span("repro.build"):
             jobs, mode = spec.build_jobs(data, p, ctx)
         rep, results = self.engine.run_site_jobs(jobs, name=spec.name)
@@ -346,14 +352,8 @@ class GridRuntime:
         modes: list[str] = []
         for j, (data, params) in enumerate(zip(datas, params_list)):
             p = spec.resolve(params)
-            ctx = RunContext(
-                measured={},
-                count_backend=self.count_backend,
-                use_kernel=self.use_kernel,
-                cluster_sync=self._cluster_sync,
-            )
             with span("repro.build"):
-                jobs, mode = spec.build_jobs(data, p, ctx)
+                jobs, mode = spec.build_jobs(data, p, self.context({}))
             modes.append(mode)
             prefix = f"r{j}/"
             for job in jobs:

@@ -23,9 +23,12 @@ Now every workload registers ONE :class:`WorkloadSpec`:
     producing the canonical JSON-able form the cross-backend conformance
     suite compares bit-for-bit;
   * **how to run it** — grid workloads provide ``build_jobs`` (SiteJob
-    DAG + sync mode, consumed by ``GridRuntime.run``) and the service-side
-    ``site_split``/``grid_params`` adapters; local (delta-served)
-    workloads provide ``local_fn`` (+ optional ``finalize``);
+    DAG + sync mode, consumed by ``GridRuntime.run``); local
+    (delta-served) workloads provide ``local_fn`` (+ optional
+    ``finalize``).  Neither sees the service: a grid workload gets its
+    per-site data from ``workflow.datasets.Dataset.sites`` (the split
+    its ``dataset_kind`` implies) and the resolved params unchanged, and
+    a local one gets the :class:`~repro.workflow.datasets.Dataset`;
   * **smoke params** — the canonical small-param points the service
     trace, the CI smoke and the registry-driven tests exercise.
 
@@ -112,9 +115,10 @@ def _reject_nonfinite(name: str, v: Any) -> None:
 
 @dataclass(frozen=True)
 class RunContext:
-    """What a ``build_jobs`` builder may use from its host runtime:
-    the measured-times dict the jobs feed, the support-count backend, the
-    kernel toggle, and (clustering) the runtime's sync-strategy factory
+    """What a ``build_jobs`` builder or a ``local_fn`` may use from its
+    host runtime (``GridRuntime.context``): the measured-times dict the
+    jobs feed, the support-count backend, the kernel toggle, and
+    (clustering) the runtime's sync-strategy factory
     ``cluster_sync(n_sites, cfg) -> (sync_fn | None, mode)``."""
 
     measured: dict = field(default_factory=dict)
@@ -130,11 +134,10 @@ class WorkloadSpec:
     Grid workloads (``runner="grid"``) run a SiteJob DAG through
     ``GridRuntime.run``: ``build_jobs(data, params, ctx)`` returns
     ``(jobs, sync_mode)`` and ``terminal`` names the job whose result is
-    the run's result.  ``site_split(ds, params, svc)`` and
-    ``grid_params(params, svc)`` adapt a service dataset + submitted
-    params into that call.  Local workloads (``runner="local"``) are
-    served in-process from per-dataset incremental state:
-    ``local_fn(ds, params, svc)`` returns the zero-arg callable the
+    the run's result; the service calls it on ``Dataset.sites`` with the
+    resolved params.  Local workloads (``runner="local"``) are served
+    in-process from per-dataset incremental state:
+    ``local_fn(ds, params, ctx)`` returns the zero-arg callable the
     service ledgers as a single-job DAG; ``finalize(ds, params, value)``
     optionally folds the result back into dataset state (k-means
     warm-start centroids).
@@ -164,8 +167,6 @@ class WorkloadSpec:
     # grid runner pieces
     build_jobs: Callable | None = None
     terminal: str = "collect"
-    site_split: Callable | None = None
-    grid_params: Callable | None = None
     # local runner pieces
     local_fn: Callable | None = None
     finalize: Callable | None = None
@@ -285,9 +286,8 @@ def validate_registry() -> list[str]:
         if not callable(spec.digest):
             problems.append(f"{where}: digest is not callable")
         if spec.runner == "grid":
-            for attr in ("build_jobs", "site_split", "grid_params"):
-                if not callable(getattr(spec, attr)):
-                    problems.append(f"{where}: grid workload missing {attr}")
+            if not callable(spec.build_jobs):
+                problems.append(f"{where}: grid workload missing build_jobs")
             if not spec.terminal:
                 problems.append(f"{where}: grid workload missing terminal job name")
         else:
@@ -354,32 +354,6 @@ def _frequent_digest(frequent: dict) -> dict:
 # it from here.
 
 
-def _tx_sites(ds, p, svc) -> list:
-    """Service-side split of a transactions dataset into per-site DBs: each
-    site's rows gathered from the dataset's packed table and uploaded.
-    Packing works row by row, so a site equals ``TransactionDB.from_dense``
-    of the same rows of ``split_transactions``, bit for bit."""
-    import jax.numpy as jnp
-
-    from repro.core.apriori import TransactionDB
-    from repro.data.synthetic import split_indices
-
-    n = p["n_sites"] if p["n_sites"] is not None else svc.n_sites
-    packed = ds.packed()
-    return [
-        TransactionDB(packed=jnp.asarray(np.take(packed, idx, axis=0)), n_items=ds.n_items,
-                      n_tx=len(idx))
-        for idx in split_indices(len(packed), int(n), seed=p["split_seed"])
-    ]
-
-
-def _pt_sites(ds, p, svc):
-    from repro.data.synthetic import split_sites
-
-    n = p["n_sites"] if p["n_sites"] is not None else svc.n_sites
-    return split_sites(ds.pooled_points(), int(n), seed=p["split_seed"])
-
-
 _SPLIT_PARAMS = (
     Param("n_sites", "int", None, "sites to split the dataset across (service default)"),
     Param("split_seed", "int", 0, "seed for the site split"),
@@ -389,10 +363,6 @@ _MINE_PARAMS = (
     Param("k", "int", 3, "maximum itemset size"),
     Param("minsup", "float", 0.1, "global minimum support fraction"),
 )
-
-
-def _mine_grid_params(p, svc) -> dict:
-    return {"k": p["k"], "minsup": p["minsup"]}
 
 
 def _mine_exec_key(ds, p) -> tuple:
@@ -407,7 +377,7 @@ def _mine_exec_key(ds, p) -> tuple:
 # -- apriori (local, delta-served) ------------------------------------------
 
 
-def _apriori_local(ds, p, svc):
+def _apriori_local(ds, p, ctx: RunContext):
     if p["min_count"] is not None:
         mc = p["min_count"]
     else:
@@ -494,8 +464,6 @@ register(WorkloadSpec(
     digest=_digest_gfm,
     build_jobs=_gfm_build,
     terminal="decide",
-    site_split=_tx_sites,
-    grid_params=_mine_grid_params,
     exec_batch_key=_mine_exec_key,
     smoke_params=({"k": 2, "minsup": 0.35}, {"k": 2, "minsup": 0.45}),
     conformance=True,
@@ -534,8 +502,6 @@ register(WorkloadSpec(
     digest=_digest_fdm,
     build_jobs=_fdm_build,
     terminal="collect",
-    site_split=_tx_sites,
-    grid_params=_mine_grid_params,
     exec_batch_key=_mine_exec_key,
     smoke_params=({"k": 2, "minsup": 0.35}, {"k": 2, "minsup": 0.45}),
     conformance=True,
@@ -575,8 +541,6 @@ register(WorkloadSpec(
     digest=_digest_cd,
     build_jobs=_cd_build,
     terminal="collect",
-    site_split=_tx_sites,
-    grid_params=_mine_grid_params,
     exec_batch_key=_mine_exec_key,
     smoke_params=({"k": 2, "minsup": 0.35}, {"k": 2, "minsup": 0.45}),
     conformance=True,
@@ -586,7 +550,7 @@ register(WorkloadSpec(
 # -- topk (local, delta-served, registered THROUGH the seam) -----------------
 
 
-def _topk_local(ds, p, svc):
+def _topk_local(ds, p, ctx: RunContext):
     from repro.core.apriori import topk_itemsets
 
     return lambda: topk_itemsets(ds.delta, p["k"], p["top"], floor=p["floor"])
@@ -622,16 +586,16 @@ register(WorkloadSpec(
 # -- kmeans (local, warm-started) -------------------------------------------
 
 
-def _kmeans_local(ds, p, svc):
+def _kmeans_local(ds, p, ctx: RunContext):
     from repro.core.kmeans import kmeans, kmeans_warm
 
     k, iters = p["k"], p["iters"]
-    x = ds.pooled_points()
+    x = ds.points()
     warm = ds.warm_centers.get(k)
     if warm is not None:
-        return lambda: kmeans_warm(x, warm, iters=iters, use_kernel=svc.use_kernel)
+        return lambda: kmeans_warm(x, warm, iters=iters, use_kernel=ctx.use_kernel)
     key = jax.random.PRNGKey(p["seed"])
-    return lambda: kmeans(key, x, k, iters=iters, use_kernel=svc.use_kernel)
+    return lambda: kmeans(key, x, k, iters=iters, use_kernel=ctx.use_kernel)
 
 
 def _kmeans_finalize(ds, p, value) -> None:
@@ -689,17 +653,6 @@ def _vcluster_build(data, p, ctx: RunContext):
     return jobs, mode
 
 
-def _vcluster_grid_params(p, svc) -> dict:
-    from repro.core.vclustering import VClusterConfig
-
-    return {
-        "key": jax.random.PRNGKey(p["seed"]),
-        "cfg": VClusterConfig(
-            k_local=p["k_local"], kmeans_iters=p["iters"], use_kernel=svc.use_kernel
-        ),
-    }
-
-
 def _vcluster_exec_key(ds, p) -> tuple | None:
     """``k_local``/``iters`` are jit-static in the site kernels and
     ``n_sites``/``split_seed`` pin the site shapes, so only the PRNG
@@ -738,8 +691,6 @@ register(WorkloadSpec(
     digest=_digest_vclustering,
     build_jobs=_vcluster_build,
     terminal="collect",
-    site_split=_pt_sites,
-    grid_params=_vcluster_grid_params,
     exec_batch_key=_vcluster_exec_key,
     smoke_params=({"k_local": 4, "iters": 8}, {"k_local": 4, "iters": 8, "seed": 1}),
     conformance=True,

@@ -14,7 +14,6 @@ import repro.core.apriori as apriori
 from repro.core.apriori import bruteforce_frequent, pack_bool_matrix
 from repro.data.synthetic import split_transactions
 from repro.launch.serve import MiningService
-from repro.workflow.registry import get_workload
 
 N_ITEMS = 40  # two packed words, the second one partial
 
@@ -32,9 +31,7 @@ def _service(batches, n_sites: int = 3) -> MiningService:
 
 
 def _sites(svc, n_sites: int, split_seed: int) -> list:
-    spec = get_workload("gfm")
-    p = spec.resolve({"n_sites": n_sites, "split_seed": split_seed})
-    return spec.site_split(svc._dataset("tx"), p, svc)
+    return svc._dataset("tx").sites(n_sites, split_seed)
 
 
 def _run(svc, app: str, params: dict):
